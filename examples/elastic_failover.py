@@ -12,6 +12,9 @@ came back.  The pause charged in the cluster simulator is exactly what this
 drill performs for real: routing-table reinstall + weight re-warm from the
 checkpoint, with the RTT (global memory) preserved.
 
+A CPU walkthrough: it forces 8 host devices.  On a TPU, run
+``python chip_smoke.py`` (one chip) or ``--four-chips`` (2x2 host).
+
 Run: PYTHONPATH=src python examples/elastic_failover.py
 """
 import os

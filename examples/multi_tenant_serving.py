@@ -10,6 +10,9 @@ is scored against the NoC flows its *actual co-resident* injects, the
 wiring the event-driven cluster scheduler (benchmarks/cluster_sim.py) uses
 at scale.
 
+A CPU walkthrough: it forces 8 host devices.  On a TPU, run
+``python chip_smoke.py`` (one chip) or ``--four-chips`` (2x2 host).
+
 Run: PYTHONPATH=src python examples/multi_tenant_serving.py
 """
 import os

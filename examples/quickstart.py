@@ -6,6 +6,9 @@
    (the paper's anti-lock-in result).
 3. Run a real (reduced) model inside each tenant's JAX mesh.
 
+A CPU walkthrough: it forces 8 host devices.  On a TPU, run
+``python chip_smoke.py`` (one chip) or ``--four-chips`` (2x2 host).
+
 Run: PYTHONPATH=src python examples/quickstart.py
 """
 import os

@@ -33,8 +33,10 @@ class DeviceTopology:
     """Binding between an NPU topology and a set of JAX devices.
 
     ``node_to_device[i]`` is the JAX device sitting at physical core id
-    ``i``.  For a TPU pod this is the ICI coordinate grid; on the CPU
-    host-platform backend it's simply an enumeration.
+    ``i``.  Devices that carry ICI ``coords`` (TPU chips) are bound by
+    position: core (row, col) is the chip at ``coords`` (x=col, y=row), so
+    topology neighbours are ICI neighbours.  Devices without coordinates
+    (the CPU host-platform backend) are bound in enumeration order.
     """
 
     topo: Topology
@@ -45,6 +47,14 @@ class DeviceTopology:
                      mesh_shape: Optional[Tuple[int, int]] = None,
                      torus: bool = False) -> "DeviceTopology":
         n = len(devices)
+        if all(getattr(d, "coords", None) is not None for d in devices):
+            devices = sorted(devices, key=lambda d: (
+                tuple(d.coords[2:]), d.coords[1], d.coords[0],
+                getattr(d, "core_on_chip", 0)))
+            xs = {d.coords[0] for d in devices}
+            ys = {d.coords[1] for d in devices}
+            if mesh_shape is None and len(xs) * len(ys) == n:
+                mesh_shape = (len(ys), len(xs))
         if mesh_shape is None:
             r = int(np.floor(np.sqrt(n)))
             while n % r:

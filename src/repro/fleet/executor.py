@@ -128,6 +128,11 @@ class ParallelExecutor:
     the start method this codebase's numpy state tolerates — hosts are
     still built *inside* the workers from picklable recipes, never
     shipped across, so the fork point carries no pod state.
+
+    The pods run the jax-free simulator.  Nothing on the chip path forks:
+    a TPU belongs to one process, and a child of a parent that has touched
+    JAX cannot reach it, so ``ServeEngine`` and ``chip_smoke.py`` stay in
+    one process.
     """
 
     def __init__(self, pod_specs: Sequence[PodSpec],

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
+from .tiling import fit_block
 
 NEG_INF = -1e30
 
@@ -73,12 +73,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     also lowers, at half MXU occupancy)."""
     B, H, S, hd = q.shape
     assert k.shape == v.shape == (B, H, S, hd)
-    bq = min(block_q, S)
-    while S % bq:
-        bq -= 1
-    bk = min(block_k, S)
-    while S % bk:
-        bk -= 1
+    bq, bk = fit_block(S, block_q, 8), fit_block(S, block_k, 8)
     n_k = S // bk
     grid = (B * H, S // bq, n_k)
     scale = 1.0 / math.sqrt(hd)
@@ -103,7 +98,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

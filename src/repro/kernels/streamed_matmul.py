@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
+from .tiling import fit_block
 
 
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
@@ -32,13 +32,6 @@ def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
     @pl.when(pl.program_id(2) == n_k - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _fit_block(dim: int, block: int) -> int:
-    b = min(block, dim)
-    while dim % b:
-        b -= 1
-    return max(b, 1)
 
 
 def streamed_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
@@ -55,8 +48,9 @@ def streamed_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
     M, K = x.shape
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
-    bm, bn, bk = _fit_block(M, block_m), _fit_block(N, block_n), \
-        _fit_block(K, block_k)
+    # M is a sublane dim (8); K and N are lane dims of x / w / out (128)
+    bm, bn, bk = fit_block(M, block_m, 8), fit_block(N, block_n, 128), \
+        fit_block(K, block_k, 128)
     n_k = K // bk
     grid = (M // bm, N // bn, n_k)
 
@@ -70,7 +64,7 @@ def streamed_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)

@@ -40,6 +40,9 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from ..checkpoint import latest_step, restore_checkpoint
     from ..configs import get_config
     from ..configs.base import reduce_for_smoke

@@ -25,7 +25,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .common import (Params, apply_rope, dense_init, get_mesh_context,
                      get_scan_unroll, rmsnorm)
@@ -273,9 +272,9 @@ def _flash_full(cfg, q, k, v, *, causal, window, use_rope, base_pos: int = 0):
                                       causal=causal, window=window)
                 return y, k_r, v_l
 
-            return shard_map(body_h, mesh=mesh, in_specs=(dq, dq, dq),
-                             out_specs=(dq, dq, dq), check_rep=False
-                             )(q, k, v)
+            return jax.shard_map(body_h, mesh=mesh, in_specs=(dq, dq, dq),
+                                 out_specs=(dq, dq, dq), check_vma=False
+                                 )(q, k, v)
         if mode == "seq" and M > 1 and S % M == 0:
             dp = P(data_spec, model_axis, None, None)
 
@@ -283,8 +282,9 @@ def _flash_full(cfg, q, k, v, *, causal, window, use_rope, base_pos: int = 0):
                 i = jax.lax.axis_index(model_axis)
                 return local(q_l, k_l, v_l, i, M)
 
-            return shard_map(body, mesh=mesh, in_specs=(dp, dp, dp),
-                             out_specs=(dp, dp, dp), check_rep=False)(q, k, v)
+            return jax.shard_map(body, mesh=mesh, in_specs=(dp, dp, dp),
+                                 out_specs=(dp, dp, dp),
+                                 check_vma=False)(q, k, v)
     return local(q, k, v, 0, 1)
 
 

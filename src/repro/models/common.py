@@ -152,15 +152,14 @@ def get_scan_unroll() -> bool:
 
 def with_logical_constraint(x: jnp.ndarray, *logical_axes: Optional[str]):
     """Apply with_sharding_constraint if rules are installed; identity
-    otherwise (lets the same model run un-meshed in unit tests)."""
+    otherwise (lets the same model run un-meshed in unit tests).  With rules
+    installed a mesh must be in scope (``jax.set_mesh``): a constraint that
+    cannot be applied raises."""
     if not _ACTIVATION_RULES:
         return x
     from jax.sharding import PartitionSpec as P
     spec = P(*[_ACTIVATION_RULES.get(a) if a else None for a in logical_axes])
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError):
-        return x  # no mesh in scope
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .common import Params, dense_init, get_moe_ff_axis
 
@@ -157,11 +156,11 @@ def moe_forward(cfg, p: Params, x: jnp.ndarray, *,
         # ``ff_axis`` (the TP/EP recipe — no FSDP gathers at the boundary)
         wg_spec = P(model_axis, None, ff_axis)
         wd_spec = P(model_axis, ff_axis, None)
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(dp, P(), wg_spec, wg_spec, wd_spec),
             out_specs=(dp, P()),
-            check_rep=False,
+            check_vma=False,
         )(x, p["router"], p["wg"], p["wu"], p["wd"])
     else:
         y, aux = _local_moe(cfg, x.reshape(B * S, d), p["router"],

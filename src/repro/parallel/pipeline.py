@@ -17,7 +17,6 @@ from typing import Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_forward(stage_fn: Callable, x_micro: jnp.ndarray, *,
@@ -63,10 +62,10 @@ def pipeline_forward(stage_fn: Callable, x_micro: jnp.ndarray, *,
         outs = jax.lax.psum(outs, axis)
         return outs
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axis), P()),
-                     out_specs=P(),
-                     check_rep=False)(stage_params, x_micro)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis), P()),
+                         out_specs=P(),
+                         check_vma=False)(stage_params, x_micro)
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:

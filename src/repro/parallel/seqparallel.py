@@ -22,7 +22,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..models.ssd import ssd_scan_ref
 
@@ -61,10 +60,10 @@ def seq_parallel_ssd(x, dt, A, B, C, *, chunk: int, mesh: Mesh,
                             init_state=state_in, return_state=True)
         return y
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis, None, None), P(None, axis, None),
                   P(), P(None, axis, None, None), P(None, axis, None, None)),
         out_specs=P(None, axis, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, dt, A, B, C)

@@ -47,13 +47,8 @@ _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions: 0.4.x returns a
-    list of per-computation dicts (take the entry-computation one, index 0),
-    newer jax returns the dict directly."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()``, or ``{}`` where XLA reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def _shape_bytes(type_str: str) -> int:

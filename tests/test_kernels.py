@@ -1,5 +1,6 @@
-"""Per-kernel validation: Pallas (interpret=True on CPU) vs pure-jnp oracle,
-sweeping shapes/dtypes + hypothesis property sweeps."""
+"""Per-kernel validation: Pallas in interpret mode (on the CPU) vs pure-jnp
+oracle, sweeping shapes/dtypes + hypothesis property sweeps.  Compiles for
+the TPU itself are in test_tpu_compile.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,7 +33,8 @@ def test_streamed_matmul_shapes(shape, dtype):
     k = jax.random.PRNGKey(0)
     x = jax.random.normal(k, (M, K), dtype)
     w = jax.random.normal(jax.random.PRNGKey(1), (K, N), dtype)
-    out = ops.matmul(x, w, block_m=64, block_n=64, block_k=64)
+    out = ops.matmul(x, w, block_m=64, block_n=128, block_k=128,
+                     interpret=True)
     _allclose(out, ref.matmul_ref(x, w), dtype)
 
 
@@ -42,7 +44,8 @@ def test_streamed_matmul_property(mi, ki, ni):
     M, K, N = 32 * mi, 32 * ki, 32 * ni
     x = jax.random.normal(jax.random.PRNGKey(mi), (M, K), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(ki), (K, N), jnp.float32)
-    out = ops.matmul(x, w, block_m=32, block_n=32, block_k=32)
+    out = ops.matmul(x, w, block_m=32, block_n=32, block_k=32,
+                     interpret=True)
     _allclose(out, ref.matmul_ref(x, w), jnp.float32)
 
 
@@ -59,7 +62,8 @@ def test_flash_attention(S, hd, causal, dtype):
     q = jax.random.normal(k, shape, dtype)
     kk = jax.random.normal(jax.random.PRNGKey(1), shape, dtype)
     v = jax.random.normal(jax.random.PRNGKey(2), shape, dtype)
-    out = ops.flash_attention(q, kk, v, causal=causal, block_q=64, block_k=64)
+    out = ops.flash_attention(q, kk, v, causal=causal, block_q=64,
+                              block_k=64, interpret=True)
     _allclose(out, ref.flash_attention_ref(q, kk, v, causal=causal), dtype)
 
 
@@ -68,8 +72,10 @@ def test_flash_blocks_dont_change_result():
     q = jax.random.normal(k, (1, 2, 256, 64))
     kk = jax.random.normal(jax.random.PRNGKey(4), (1, 2, 256, 64))
     v = jax.random.normal(jax.random.PRNGKey(5), (1, 2, 256, 64))
-    a = ops.flash_attention(q, kk, v, block_q=64, block_k=128)
-    b = ops.flash_attention(q, kk, v, block_q=128, block_k=64)
+    a = ops.flash_attention(q, kk, v, block_q=64, block_k=128,
+                            interpret=True)
+    b = ops.flash_attention(q, kk, v, block_q=128, block_k=64,
+                            interpret=True)
     _allclose(a, b, jnp.float32)
 
 
@@ -87,7 +93,7 @@ def test_ssd_scan(S, chunk, dtype):
     A = -jnp.exp(jax.random.normal(jax.random.PRNGKey(2), (H,)) * 0.3)
     B = (jax.random.normal(jax.random.PRNGKey(3), (b, S, N)) * 0.5).astype(dtype)
     C = (jax.random.normal(jax.random.PRNGKey(4), (b, S, N)) * 0.5).astype(dtype)
-    out = ops.ssd_scan(x, dt, A, B, C, chunk=chunk)
+    out = ops.ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=True)
     r = ref.ssd_scan_kernel_ref(x, dt, A, B, C, chunk)
     scale = float(jnp.abs(r.astype(jnp.float32)).max()) + 1e-6
     err = float(jnp.abs(out.astype(jnp.float32) - r.astype(jnp.float32)).max())
@@ -103,8 +109,8 @@ def test_ssd_chunking_invariance():
     A = -jnp.exp(jax.random.normal(jax.random.PRNGKey(9), (H,)) * 0.3)
     B = jax.random.normal(jax.random.PRNGKey(10), (b, S, N)) * 0.5
     C = jax.random.normal(jax.random.PRNGKey(11), (b, S, N)) * 0.5
-    a = ops.ssd_scan(x, dt, A, B, C, chunk=16)
-    bb = ops.ssd_scan(x, dt, A, B, C, chunk=64)
+    a = ops.ssd_scan(x, dt, A, B, C, chunk=16, interpret=True)
+    bb = ops.ssd_scan(x, dt, A, B, C, chunk=64, interpret=True)
     _allclose(a, bb, jnp.float32)
 
 
@@ -120,5 +126,21 @@ def test_decode_attention(S, length, dtype):
     q = jax.random.normal(k, (B, H, hd), dtype)
     kc = jax.random.normal(jax.random.PRNGKey(1), (B, S, H, hd), dtype)
     vc = jax.random.normal(jax.random.PRNGKey(2), (B, S, H, hd), dtype)
-    out = ops.decode_attention(q, kc, vc, length=length, block_s=128)
+    out = ops.decode_attention(q, kc, vc, length=length, block_s=128,
+                               interpret=True)
     _allclose(out, ref.decode_attention_ref(q, kc, vc, length), dtype)
+
+
+def test_decode_attention_length_is_traced():
+    """Every decode position reuses one compiled kernel."""
+    B, S, H, hd = 1, 256, 2, 64
+    q = jax.random.normal(jax.random.PRNGKey(0), (B, H, hd))
+    kc = jax.random.normal(jax.random.PRNGKey(1), (B, S, H, hd))
+    vc = jax.random.normal(jax.random.PRNGKey(2), (B, S, H, hd))
+    ops.decode_attention.clear_cache()
+    for length in (1, 77, 256):
+        out = ops.decode_attention(q, kc, vc, jnp.int32(length), block_s=128,
+                                   interpret=True)
+        _allclose(out, ref.decode_attention_ref(q, kc, vc, length),
+                  jnp.float32)
+    assert ops.decode_attention._cache_size() == 1

@@ -176,3 +176,89 @@ class TestServing:
         assert all(r.done and len(r.out_tokens) == 4 for r in reqs)
         assert all(0 <= t < cfg.vocab_size
                    for r in reqs for t in r.out_tokens)
+
+    def test_submit_rejects_cache_overrun(self):
+        from repro.serve import EngineConfig, ServeEngine
+        bundle, cfg = _bundle()
+        eng = ServeEngine(bundle, None, EngineConfig(batch_size=1, max_seq=16))
+        eng.submit(np.zeros(8, np.int32), max_new_tokens=9)   # fills 16
+        with pytest.raises(ValueError, match="max_seq"):
+            eng.submit(np.zeros(8, np.int32), max_new_tokens=10)
+
+    def test_decode_reuses_one_compile(self):
+        """The engine's AOT compile serves every decode step."""
+        from repro.serve import EngineConfig, ServeEngine
+        bundle, cfg = _bundle()
+        params = bundle.init(jax.random.PRNGKey(0))
+        eng = ServeEngine(bundle, params, EngineConfig(batch_size=2,
+                                                       max_seq=32))
+        eng.compile(prompt_len=8)
+        for _ in range(2):
+            eng.submit(np.arange(8, dtype=np.int32), max_new_tokens=5)
+        eng.run()
+        assert eng._prefill._cache_size() == 1
+        assert eng._decode._cache_size() == 1
+
+
+class TestLaunch:
+    def test_compile_cache_env_wins(self, monkeypatch, tmp_path):
+        from repro.launch import compile_cache as cc
+        monkeypatch.setenv(cc.ENV_VAR, str(tmp_path))
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a: calls.append(a))
+        assert cc.compile_cache_dir() == str(tmp_path)
+        assert cc.enable_compile_cache() == str(tmp_path)
+        assert calls == []            # JAX reads the variable itself
+
+    def test_compile_cache_default_is_fixed_in_checkout(self, monkeypatch):
+        from pathlib import Path
+        from repro.launch import compile_cache as cc
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+        a, b = cc.compile_cache_dir(), cc.compile_cache_dir()
+        assert a == b
+        root = Path(__file__).resolve().parent.parent
+        assert Path(a).parent == root
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *args: calls.append(args))
+        assert cc.enable_compile_cache() == a
+        assert calls == [("jax_compilation_cache_dir", a)]
+
+    def test_constraint_without_mesh_raises(self):
+        from repro.models.common import (clear_mesh_context,
+                                         set_activation_rules,
+                                         with_logical_constraint)
+        x = jnp.ones((4, 4))
+        assert with_logical_constraint(x, "batch", None) is x
+        set_activation_rules({"batch": ("data",)})
+        try:
+            with pytest.raises(RuntimeError, match="mesh"):
+                jax.jit(lambda a: with_logical_constraint(a, "batch", None)
+                        )(x)
+        finally:
+            clear_mesh_context()
+
+
+class TestDeviceBinding:
+    def test_tpu_devices_bind_by_coords(self):
+        """Core (row, col) is the chip at coords (x=col, y=row), whatever
+        order the devices are listed in."""
+        import random
+        from types import SimpleNamespace
+        from repro.core import DeviceTopology
+        devs = [SimpleNamespace(id=i, coords=(x, y, 0), core_on_chip=0)
+                for i, (x, y) in enumerate((x, y) for y in range(2)
+                                           for x in range(3))]
+        random.Random(0).shuffle(devs)
+        dt = DeviceTopology.from_devices(devs)
+        assert sorted(dt.topo.coords.values()) == [
+            (r, c) for r in range(2) for c in range(3)]
+        for node, (r, c) in dt.topo.coords.items():
+            assert dt.device_for(node).coords[:2] == (c, r)
+
+    def test_devices_without_coords_bind_in_order(self):
+        from repro.core import DeviceTopology
+        devs = jax.devices()[:4]
+        dt = DeviceTopology.from_devices(devs, (2, 2))
+        assert [dt.device_for(i) for i in range(4)] == list(devs)

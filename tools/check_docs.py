@@ -33,7 +33,8 @@ DOC_FILES = ("README.md", "docs/architecture.md", "DESIGN.md",
 # (examples/ scripts take no arguments and are only checked for existence)
 ARGPARSE_SCRIPTS = ("benchmarks/cluster_sim.py", "benchmarks/mapping_engine.py",
                     "benchmarks/serving_sim.py", "benchmarks/fleet_sim.py",
-                    "benchmarks/chaos_sim.py", "tools/trace_report.py")
+                    "benchmarks/chaos_sim.py", "tools/trace_report.py",
+                    "chip_smoke.py")
 
 # non-repo executables we do not try to resolve
 SKIP_MODULES = ("pytest", "pip", "doctest", "venv")
