@@ -1,7 +1,7 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [--reduced]``.
 
 Spins up the batched prefill/decode engine on a model from the config
-registry, with random weights made on the device from ``--seed``, and
+registry, with random weights made on the device from seed 0, and
 serves a handful of synthetic equal-length requests.  Without
 ``--reduced`` the model has its published widths; ``chip_smoke.py`` at the
 repo root drives this same path on a TPU.
@@ -50,7 +50,6 @@ def main() -> int:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=8)
     ap.add_argument("--max-seq", type=int, default=128)
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
     from ..configs import get_config
@@ -61,10 +60,8 @@ def main() -> int:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_for_smoke(cfg)
-    engine = make_engine(cfg, batch_size=args.requests, max_seq=args.max_seq,
-                         seed=args.seed)
-    submit_prompts(engine, args.requests, args.prompt_len, args.new_tokens,
-                   args.seed)
+    engine = make_engine(cfg, batch_size=args.requests, max_seq=args.max_seq)
+    submit_prompts(engine, args.requests, args.prompt_len, args.new_tokens)
     reqs = engine.run()
     for r in reqs:
         print(f"req {r.rid}: {r.out_tokens}")

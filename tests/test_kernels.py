@@ -41,10 +41,12 @@ def test_streamed_matmul_shapes(shape, dtype):
 @given(st.integers(1, 5), st.integers(1, 5), st.integers(1, 5))
 @settings(max_examples=8, deadline=None)
 def test_streamed_matmul_property(mi, ki, ni):
-    M, K, N = 32 * mi, 32 * ki, 32 * ni
+    # (8, 128)-aligned blocks: every example spans mi x ni output tiles
+    # and accumulates over ki K-blocks
+    M, K, N = 32 * mi, 128 * ki, 128 * ni
     x = jax.random.normal(jax.random.PRNGKey(mi), (M, K), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(ki), (K, N), jnp.float32)
-    out = ops.matmul(x, w, block_m=32, block_n=32, block_k=32,
+    out = ops.matmul(x, w, block_m=32, block_n=128, block_k=128,
                      interpret=True)
     _allclose(out, ref.matmul_ref(x, w), jnp.float32)
 
