@@ -84,11 +84,10 @@ def mem(dev, key: str = "peak_bytes_in_use") -> int:
     return dev.memory_stats()[key]
 
 
-def programs(engine) -> str:
-    """Compiled programs per engine step: 1 each means the ahead-of-time
-    compile served every call."""
-    return (f"prefill {engine._prefill._cache_size()} decode "
-            f"{engine._decode._cache_size()}")
+def programs(engine) -> int:
+    """Compiles (or compile-cache loads) of the engine's two steps: 2 means
+    the ahead-of-time compile served every call."""
+    return engine.stats["compiles"]
 
 
 def check_served(reqs, n_new: int, vocab: int) -> None:
@@ -122,8 +121,8 @@ def serve_one_chip(dev):
         f"{st['prefill_s']} s; decode {st['decode_s'] / st['decode_steps']}"
         f" s/token-step at batch {N_REQ}")
     log(f"peak_bytes_in_use {mem(dev)}")
-    log(f"programs compiled: {programs(engine)}")
-    if programs(engine) != "prefill 1 decode 1":
+    log(f"step compiles: {programs(engine)}")
+    if programs(engine) != 2:
         raise AssertionError("the engine recompiled while serving")
     return engine, reqs
 
@@ -288,7 +287,7 @@ def serve_on_mesh(mesh) -> None:
         f"{st['decode_s'] / st['decode_steps']} s/token-step")
     log("peak_bytes_in_use per device " + str(
         {d.id: mem(d) for d in mesh.devices.flat}))
-    log(f"programs compiled: {programs(engine)}")
+    log(f"step compiles: {programs(engine)}")
 
 
 def main() -> int:

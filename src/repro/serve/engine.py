@@ -6,10 +6,18 @@ Requests queue up, get micro-batched into a fixed-size decode batch
 jit'd decode step advances every active slot one token per tick — the
 standard orchestration loop of an LLM server.  The same loop runs on the
 CPU for the examples and tests and on a TPU (``chip_smoke.py``).
+
+The loop is always traced: it opens a short ``jax.profiler.TraceAnnotation``
+span (``serve.*``, with the batch's id among its args) around each piece of
+host work, which a profiler session records on the device trace's clock
+and which costs about a microsecond without one.  ``ServeEngine.stats``
+counts requests admitted and compiles of the two steps, and each
+:class:`Request` carries the host clock of its submission and admission.
 """
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -21,6 +29,34 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..models.common import get_mesh_context
 from ..parallel import sharding as shd
 
+# host spans of the serving loop.  Each is opened and closed inside one
+# step, so that none is open when a profile starts (a span opened before
+# the profiler starts is never recorded).
+SPAN_ADMIT = "serve.admit"        # batch, n, rid0, rid1
+SPAN_PREFILL = "serve.prefill"    # batch, prompt_len
+SPAN_DISPATCH = "serve.dispatch"  # batch, pos, live
+SPAN_READ = "serve.read"          # batch
+SPAN_EMIT = "serve.emit"          # batch, n
+
+# compiles (or compile-cache loads) of the two steps, as JAX reports them;
+# a compile runs in the thread that called the step, so each thread counts
+# its own and an engine adds what its calls added
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+STEP_FUNS = frozenset({"jit(_prefill_step)", "jit(_decode_step)"})
+_compiles = threading.local()
+
+
+def _thread_compiles() -> int:
+    return getattr(_compiles, "n", 0)
+
+
+def _count_compile(event: str, duration_s: float, **kwargs) -> None:
+    if event == COMPILE_EVENT and kwargs.get("fun_name") in STEP_FUNS:
+        _compiles.n = _thread_compiles() + 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
 
 @dataclasses.dataclass
 class Request:
@@ -29,6 +65,9 @@ class Request:
     max_new_tokens: int = 16
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    # host clock (perf_counter) at ``submit`` and when its batch was formed
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -87,9 +126,13 @@ class ServeEngine:
         self._prefill = jax.jit(self._prefill_step)
         self.queue: List[Request] = []
         # prefill_s / decode_s: host wall time of the device work, each
-        # window closed by a device sync
+        # window closed by a device sync; tokens_out counts each request's
+        # prefill token, so (tokens_out - admitted) / (decode_steps x
+        # batch_size) is the share of decode slots that emitted a token;
+        # compiles: compiles (or compile-cache loads) of the two steps
         self.stats: Dict[str, float] = {"prefills": 0, "decode_steps": 0,
-                                        "tokens_out": 0, "prefill_s": 0.0,
+                                        "tokens_out": 0, "admitted": 0,
+                                        "compiles": 0, "prefill_s": 0.0,
                                         "decode_s": 0.0}
 
     def _step_shardings(self):
@@ -125,6 +168,7 @@ class ServeEngine:
         batch = jax.eval_shape(
             lambda: self._pad_batch([Request(-1, np.zeros(prompt_len,
                                                           np.int32))])[0])
+        compiles0 = _thread_compiles()
         t0 = time.perf_counter()
         prefill = self._prefill.lower(self.params, batch).compile()
         out = {"prefill_s": time.perf_counter() - t0}
@@ -139,6 +183,7 @@ class ServeEngine:
         self._decode.lower(self.params, caches, tok,
                            jax.ShapeDtypeStruct((), jnp.int32)).compile()
         out["decode_s"] = time.perf_counter() - t0
+        self.stats["compiles"] += _thread_compiles() - compiles0
         return out
 
     def submit(self, prompt: np.ndarray, max_new_tokens: int = 16) -> Request:
@@ -148,7 +193,8 @@ class ServeEngine:
                 f"prompt {len(prompt)} + {max_new_tokens} new tokens "
                 f"overrun max_seq {self.ecfg.max_seq}")
         req = Request(rid=len(self.queue), prompt=np.asarray(prompt),
-                      max_new_tokens=max_new_tokens)
+                      max_new_tokens=max_new_tokens,
+                      submitted_at=time.perf_counter())
         self.queue.append(req)
         return req
 
@@ -172,35 +218,63 @@ class ServeEngine:
     # -- main loop -----------------------------------------------------------
     def run(self, max_ticks: int = 64) -> List[Request]:
         """Process the queue to completion (or tick budget)."""
+        compiles0 = _thread_compiles()
         pending = [r for r in self.queue if not r.done]
         while pending and max_ticks > 0:
-            reqs = pending[: self.ecfg.batch_size]
+            max_ticks -= self._serve_batch(pending[: self.ecfg.batch_size],
+                                           max_ticks)
+            pending = [r for r in self.queue if not r.done]
+        self.stats["compiles"] += _thread_compiles() - compiles0
+        return self.queue
+
+    def _serve_batch(self, reqs: List[Request], max_ticks: int) -> int:
+        """Prefill ``reqs`` as one batch, then decode it for at most
+        ``max_ticks`` steps; returns the steps taken."""
+        b = self.stats["prefills"]          # the batch's id: batches before it
+        with jax.profiler.TraceAnnotation(SPAN_ADMIT, batch=b, n=len(reqs),
+                                          rid0=reqs[0].rid,
+                                          rid1=reqs[-1].rid):
+            now = time.perf_counter()
+            for r in reqs:
+                r.admitted_at = now
+            self.stats["admitted"] += len(reqs)
             batch, S = self._pad_batch(reqs)
+        with jax.profiler.TraceAnnotation(SPAN_PREFILL, batch=b,
+                                          prompt_len=S):
             t0 = time.perf_counter()
             tok, caches = self._prefill(self.params, batch)
             jax.block_until_ready((tok, caches))
             self.stats["prefill_s"] += time.perf_counter() - t0
-            self.stats["prefills"] += 1
+        self.stats["prefills"] += 1
+        with jax.profiler.TraceAnnotation(SPAN_READ, batch=b):
             host_tok = np.asarray(tok)
+        with jax.profiler.TraceAnnotation(SPAN_EMIT, batch=b, n=len(reqs)):
             for i, r in enumerate(reqs):
                 r.out_tokens.append(int(host_tok[i, 0]))
-                self.stats["tokens_out"] += 1
-            pos = S
-            steps = max(r.max_new_tokens for r in reqs) - 1
-            t0 = time.perf_counter()
-            for _ in range(min(steps, max_ticks)):
+            self.stats["tokens_out"] += len(reqs)
+        # slots still short of their max_new_tokens
+        live = sum(len(r.out_tokens) < r.max_new_tokens for r in reqs)
+        pos = S
+        steps = max(0, min(max(r.max_new_tokens for r in reqs) - 1,
+                           max_ticks))
+        t0 = time.perf_counter()
+        for _ in range(steps):
+            with jax.profiler.TraceAnnotation(SPAN_DISPATCH, batch=b,
+                                              pos=pos, live=live):
                 tok, caches = self._decode(self.params, caches, tok,
                                            np.int32(pos))
+            with jax.profiler.TraceAnnotation(SPAN_READ, batch=b):
                 host_tok = np.asarray(tok)
-                self.stats["decode_steps"] += 1
+            self.stats["decode_steps"] += 1
+            with jax.profiler.TraceAnnotation(SPAN_EMIT, batch=b, n=live):
+                self.stats["tokens_out"] += live
+                live = 0
                 for i, r in enumerate(reqs):
                     if len(r.out_tokens) < r.max_new_tokens:
                         r.out_tokens.append(int(host_tok[i, 0]))
-                        self.stats["tokens_out"] += 1
-                pos += 1
-                max_ticks -= 1
-            self.stats["decode_s"] += time.perf_counter() - t0
-            for r in reqs:
-                r.done = True
-            pending = [r for r in self.queue if not r.done]
-        return self.queue
+                        live += len(r.out_tokens) < r.max_new_tokens
+            pos += 1
+        self.stats["decode_s"] += time.perf_counter() - t0
+        for r in reqs:
+            r.done = True
+        return steps

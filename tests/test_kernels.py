@@ -139,10 +139,21 @@ def test_decode_attention_length_is_traced():
     q = jax.random.normal(jax.random.PRNGKey(0), (B, H, hd))
     kc = jax.random.normal(jax.random.PRNGKey(1), (B, S, H, hd))
     vc = jax.random.normal(jax.random.PRNGKey(2), (B, S, H, hd))
+    compiles = []
+
+    def count(event, duration_s, **kwargs):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and kwargs.get("fun_name") == "jit(decode_attention)"):
+            compiles.append(duration_s)
+
     ops.decode_attention.clear_cache()
-    for length in (1, 77, 256):
-        out = ops.decode_attention(q, kc, vc, jnp.int32(length), block_s=128,
-                                   interpret=True)
-        _allclose(out, ref.decode_attention_ref(q, kc, vc, length),
-                  jnp.float32)
-    assert ops.decode_attention._cache_size() == 1
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        for length in (1, 77, 256):
+            out = ops.decode_attention(q, kc, vc, jnp.int32(length),
+                                       block_s=128, interpret=True)
+            _allclose(out, ref.decode_attention_ref(q, kc, vc, length),
+                      jnp.float32)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    assert len(compiles) == 1

@@ -193,11 +193,11 @@ class TestServing:
         eng = ServeEngine(bundle, params, EngineConfig(batch_size=2,
                                                        max_seq=32))
         eng.compile(prompt_len=8)
+        assert eng.stats["compiles"] == 2
         for _ in range(2):
             eng.submit(np.arange(8, dtype=np.int32), max_new_tokens=5)
         eng.run()
-        assert eng._prefill._cache_size() == 1
-        assert eng._decode._cache_size() == 1
+        assert eng.stats["compiles"] == 2
 
 
 class TestLaunch:
