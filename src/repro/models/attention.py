@@ -293,46 +293,77 @@ def _flash_full(cfg, q, k, v, *, causal, window, use_rope, base_pos: int = 0):
 # ---------------------------------------------------------------------------
 
 def init_kv_cache(cfg, batch: int, max_seq: int, dtype) -> Dict[str, jnp.ndarray]:
-    """Sliding-window archs keep only a ring buffer of ``window`` entries."""
+    """(B,S,KV*hd) per leaf, the KV heads fused into one minor dim so that a
+    position's row is contiguous.  Sliding-window archs keep only a ring
+    buffer of ``window`` entries."""
     KV, hd = cfg.n_kv_heads, cfg.head_dim_
     S = min(max_seq, cfg.sliding_window) if cfg.sliding_window else max_seq
     return {
-        "k": jnp.zeros((batch, S, KV, hd), dtype),
-        "v": jnp.zeros((batch, S, KV, hd), dtype),
+        "k": jnp.zeros((batch, S, KV * hd), dtype),
+        "v": jnp.zeros((batch, S, KV * hd), dtype),
     }
 
 
-def update_cache(cfg, cache: Dict[str, jnp.ndarray], k_new, v_new,
+def fuse_heads(x: jnp.ndarray) -> jnp.ndarray:
+    """(...,KV,hd) -> (...,KV*hd), the layout of the decode cache."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def cache_slot(cfg, S: int, pos: jnp.ndarray) -> jnp.ndarray:
+    """The cache row of position ``pos`` (ring-indexed under sliding
+    window)."""
+    return pos % S if cfg.sliding_window else pos
+
+
+def with_new_row(cfg, cache: Dict[str, jnp.ndarray], k_new, v_new,
                  pos: jnp.ndarray) -> Dict[str, jnp.ndarray]:
-    """Write one token's K/V at ``pos`` (ring-indexed under sliding window).
-
-    One-hot select keeps the sequence dim shardable (split-KV decode);
-    k_new/v_new: (B,1,KV,hd).
-    """
+    """One layer's K/V (B,S,KV*hd) as decode attends them: the new token's
+    row at its slot, the cache's rows elsewhere.  The select fuses into
+    attention's read of the slab and is never stored; ``update_cache``
+    writes the row.  k_new/v_new: (B,1,KV*hd) in the cache's dtype."""
     S = cache["k"].shape[1]
-    slot = pos % S if cfg.sliding_window else pos
-    iota = jnp.arange(S)
-    hit = (iota == slot)[None, :, None, None]
-    return {
-        "k": jnp.where(hit, k_new.astype(cache["k"].dtype), cache["k"]),
-        "v": jnp.where(hit, v_new.astype(cache["v"].dtype), cache["v"]),
-    }
+    hit = (jnp.arange(S) == cache_slot(cfg, S, pos))[None, :, None]
+    return {"k": jnp.where(hit, k_new, cache["k"]),
+            "v": jnp.where(hit, v_new, cache["v"])}
+
+
+def update_cache(cfg, cache: Dict[str, jnp.ndarray],
+                 rows: Dict[str, jnp.ndarray], pos: jnp.ndarray
+                 ) -> Dict[str, jnp.ndarray]:
+    """Write every layer's new K/V row (L,B,1,KV*hd) into the stacked cache
+    (L,B,S,KV*hd) at ``pos``'s slot.
+
+    One ``dynamic_update_slice`` per leaf, in place when the cache is
+    donated: nothing else of the slab is read or written.  A split-KV
+    (sequence-sharded) cache takes the same write; GSPMD lowers it to an
+    in-place row write on each shard (compiled for a described v5e 2x2).
+    """
+    return {n: jax.lax.dynamic_update_slice(
+                cache[n], r.astype(cache[n].dtype),
+                (0, 0, cache_slot(cfg, cache[n].shape[2], pos), 0))
+            for n, r in rows.items()}
 
 
 def decode_attention(cfg, q, cache: Dict[str, jnp.ndarray],
                      pos: jnp.ndarray) -> jnp.ndarray:
     """Single-token attention over the (possibly seq-sharded) cache.
 
-    q: (B,1,H,hd) -> (B,1,H,hd).  Exact softmax even when the cache's seq dim
-    is sharded: the reductions lower to psum-style collectives under GSPMD.
+    q: (B,1,H,hd), cache k/v: (B,S,KV*hd) -> (B,1,H,hd).  Each query head
+    is laid into its KV head's lanes of a KV*hd row, zeros elsewhere, so
+    one matmul over the fused lanes scores every head against its own K
+    and the cache is read in its own layout.  Exact softmax even when the
+    cache's seq dim is sharded: the reductions lower to psum-style
+    collectives under GSPMD.
     """
     B, _, H, hd = q.shape
     k, v = cache["k"], cache["v"]
-    S, KV = k.shape[1], k.shape[2]
+    S, KV = k.shape[1], k.shape[2] // hd
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, KV, G, hd)
-    s = jnp.einsum("bkgh,bskh->bkgs", qg.astype(jnp.float32),
+    own = jnp.eye(KV, dtype=jnp.float32)
+    qg = jnp.einsum("bkgh,kl->bkglh", q.reshape(B, KV, G, hd).astype(
+        jnp.float32), own).reshape(B, KV, G, KV * hd)
+    s = jnp.einsum("bkgc,bsc->bkgs", qg,
                    k.astype(jnp.float32)) * scale          # (B,KV,G,S)
     iota = jnp.arange(S)
     if cfg.sliding_window:
@@ -347,8 +378,9 @@ def decode_attention(cfg, q, cache: Dict[str, jnp.ndarray],
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bkgs,bskh->bkgh", p / jnp.maximum(l, 1e-30),
-                     v.astype(jnp.float32))
+    out = jnp.einsum("bkgs,bsc->bkgc", p / jnp.maximum(l, 1e-30),
+                     v.astype(jnp.float32)).reshape(B, KV, G, KV, hd)
+    out = jnp.einsum("bkglh,kl->bkgh", out, own)
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
@@ -367,10 +399,11 @@ def attention_forward(cfg, p: Params, x: jnp.ndarray, *,
 
     * train/prefill (cache=None): chunked flash attention; returns
       (y, (k_roped, v)) so prefill can seed the decode cache.
-    * decode (cache given, x is (B,1,d)): split-KV decode; returns
-      (y, new_cache).
-    * cross-attention: pass precomputed_kv=(k, v) from the encoder; with a
-      cache dict containing them, decode just reads.
+    * decode (cache given, x is (B,1,d)): split-KV decode over the layer's
+      cache with the new token's K/V row selected in; returns (y, row), the
+      row for ``update_cache`` to write.
+    * cross-attention decode: pass precomputed_kv=(k, v), the encoder's
+      K/V (B,S_enc,KV*hd) from the decode cache; decode just reads them.
     """
     H, hd = cfg.n_heads, cfg.head_dim_
 
@@ -380,13 +413,8 @@ def attention_forward(cfg, p: Params, x: jnp.ndarray, *,
         if cfg.qk_norm:
             q = rmsnorm(q, p["q_norm"], cfg.rms_eps)
         k, v = precomputed_kv
-        if x.shape[1] == 1:  # cross-attention decode: plain gathered attend
-            y = decode_attention(cfg, q, {"k": k, "v": v},
-                                 jnp.asarray(k.shape[1] - 1))
-        else:
-            qpos = jnp.arange(x.shape[1])
-            kpos = jnp.arange(k.shape[1])
-            y = chunked_attention(cfg, q, k, v, qpos, kpos, causal=False)
+        y = decode_attention(cfg, q, {"k": k, "v": v},
+                             jnp.asarray(k.shape[1] - 1))
         y = jnp.einsum("bsh,hd->bsd", y.reshape(*y.shape[:-2], H * hd),
                        p["wo"])
         return y, None
@@ -407,11 +435,14 @@ def attention_forward(cfg, p: Params, x: jnp.ndarray, *,
         if use_rope:
             q = apply_rope(q, cache_pos[None], cfg.rope_theta)
             k = apply_rope(k, cache_pos[None], cfg.rope_theta)
-        new_cache = update_cache(cfg, cache, k, v, cache_pos)
-        y = decode_attention(cfg, q, new_cache, cache_pos)
+        row = {"k": fuse_heads(k).astype(cache["k"].dtype),
+               "v": fuse_heads(v).astype(cache["v"].dtype)}
+        y = decode_attention(cfg, q, with_new_row(cfg, cache, row["k"],
+                                                  row["v"], cache_pos),
+                             cache_pos)
         y = jnp.einsum("bsh,hd->bsd", y.reshape(*y.shape[:-2], H * hd),
                        p["wo"])
-        return y, new_cache
+        return y, row
 
     y, k_r, v_r = _flash_full(cfg, q, k, v, causal=causal, window=window,
                               use_rope=use_rope)

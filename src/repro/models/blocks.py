@@ -20,7 +20,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .attention import attention_forward, attention_init, init_kv_cache
+from .attention import (attention_forward, attention_init, fuse_heads,
+                        init_kv_cache)
 from .common import Params, apply_norm, dense_init, norm_init
 from .moe import moe_forward, moe_init
 from .ssd import init_ssd_cache, ssd_decode_step, ssd_forward, ssd_init
@@ -97,9 +98,11 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
                   ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
     """Returns (y, new_cache, aux_loss).  ``cache`` is this layer's slice.
 
-    In full (train/prefill) mode the returned 'cache' holds the K/V computed
-    for the sequence (prefill seeds the decode cache from it); SSM blocks
-    return their final state + conv tails.
+    In decode the returned 'cache' holds what the step writes: the new
+    token's K/V row and the new SSM state + conv tails; cross-attention K/V
+    are read and not returned.  In full (train/prefill) mode it holds the
+    K/V computed for the sequence (prefill seeds the decode cache from it);
+    SSM blocks return their final state + conv tails.
     """
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
@@ -129,7 +132,7 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
             cfg, p["attn"], h, causal=causal, window=window,
             use_rope=use_rope)
         if kv is not None:
-            new_cache.update({"k": kv[0], "v": kv[1]})
+            new_cache["k"], new_cache["v"] = map(fuse_heads, kv)
 
     if kind == "hybrid":
         if decoding:
@@ -149,14 +152,13 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
             y_cross, _ = attention_forward(
                 cfg, p["cross"], h,
                 precomputed_kv=(cache["cross_k"], cache["cross_v"]))
-            new_cache["cross_k"] = cache["cross_k"]
-            new_cache["cross_v"] = cache["cross_v"]
         else:
             y_cross, ckv = attention_forward(cfg, p["cross"], h,
                                              kv_x=enc_out, causal=False,
                                              use_rope=False)
             if ckv is not None:
-                new_cache["cross_k"], new_cache["cross_v"] = ckv
+                new_cache["cross_k"], new_cache["cross_v"] = map(
+                    fuse_heads, ckv)
         x = x + y_cross
 
     # --- FFN sub-block ---

@@ -15,10 +15,30 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .attention import update_cache
 from .blocks import block_forward, block_init, init_block_cache
 from .common import (Params, apply_norm, dtype_of, embed_init,
                      get_scan_unroll, norm_init, softmax_cross_entropy,
                      with_logical_constraint)
+
+# decode-cache leaves that a decode step rewrites whole, layer by layer
+STATES = ("state", "conv_x", "conv_BC")
+
+
+def layer_slice(cache: Dict[str, jnp.ndarray], layer) -> Dict[str, Any]:
+    """Layer ``layer`` of each leaf of a stacked cache."""
+    return {n: jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+            for n, c in cache.items()}
+
+
+def write_layer(cache: Dict[str, jnp.ndarray], layer,
+                new: Dict[str, jnp.ndarray]) -> Dict[str, Any]:
+    """``cache`` with layer ``layer`` of each leaf named in ``new``
+    overwritten: one ``dynamic_update_slice`` per leaf, in place when the
+    cache is donated."""
+    return {**cache, **{n: jax.lax.dynamic_update_index_in_dim(
+        cache[n], v.astype(cache[n].dtype), layer, 0)
+        for n, v in new.items()}}
 
 
 def layer_plan(cfg) -> List[Tuple[Tuple[str, ...], int]]:
@@ -116,35 +136,59 @@ def build_inputs(cfg, p: Params, batch: Dict[str, jnp.ndarray]) -> jnp.ndarray:
 def _scan_stack(cfg, stack_params, x, kinds: Tuple[str, ...], *,
                 caches=None, cache_pos=None, collect_cache: bool = False,
                 enc_out=None):
-    """Scan one homogeneous stack.  Returns (x, new_caches_or_None, aux)."""
+    """Scan one homogeneous stack.  Returns (x, new_caches_or_None, aux).
+
+    Decode (``caches`` given) writes the cache in place and copies no
+    layer's slab: each layer attends its K/V slice with the new token's row
+    selected in as the slice is read, and after the scan one row per layer
+    is written into the stacked K/V cache.  SSM states and conv tails, which
+    a step rewrites whole, are carried through the scan and overwritten
+    layer by layer.  The K/V cache is not carried: the TPU compiler lays a
+    carried K/V cache out unlike the donated argument and copies it whole
+    into and out of the loop (compiled for a described v5e).
+    """
     init = (x, jnp.zeros((), jnp.float32))
+    unroll = True if get_scan_unroll() else 1
+    if caches is not None:
+        read = {b: {n: c for n, c in cache.items() if n not in STATES}
+                for b, cache in caches.items()}
+        carried = {b: {n: c for n, c in cache.items() if n in STATES}
+                   for b, cache in caches.items()}
 
-    def apply_layer(h, aux, sp, layer_cache):
-        new_caches = {}
+        def step(carry, xs):
+            h, aux, states = carry
+            sp, lc, layer = xs
+            states, rows = dict(states), {}
+            for i, kind in enumerate(kinds):
+                b = f"b{i}"
+                h, new, a = block_forward(
+                    cfg, sp[b], h, kind,
+                    cache={**lc[b], **layer_slice(states[b], layer)},
+                    cache_pos=cache_pos, enc_out=enc_out)
+                aux = aux + a
+                states[b] = write_layer(states[b], layer, {
+                    n: v for n, v in new.items() if n in STATES})
+                rows[b] = {n: v for n, v in new.items() if n not in STATES}
+            return (h, aux, states), rows
+
+        count = jax.tree.leaves(stack_params)[0].shape[0]
+        (x, aux, states), rows = jax.lax.scan(
+            step, init + (carried,), (stack_params, read, jnp.arange(count)),
+            unroll=unroll)
+        return x, {b: {**caches[b], **states[b],
+                       **update_cache(cfg, caches[b], rows[b], cache_pos)}
+                   for b in caches}, aux
+
+    def body(carry, sp):
+        h, aux = carry
+        ncs = {}
         for i, kind in enumerate(kinds):
-            lc = layer_cache[f"b{i}"] if layer_cache is not None else None
-            h, nc, a = block_forward(cfg, sp[f"b{i}"], h, kind,
-                                     cache=lc, cache_pos=cache_pos,
-                                     enc_out=enc_out)
+            h, ncs[f"b{i}"], a = block_forward(cfg, sp[f"b{i}"], h, kind,
+                                               enc_out=enc_out)
             aux = aux + a
-            new_caches[f"b{i}"] = nc
-        return h, aux, new_caches
-
-    unroll = get_scan_unroll()
-    if caches is None:
-        def body(carry, sp):
-            h, aux, ncs = apply_layer(carry[0], carry[1], sp, None)
-            return (h, aux), (ncs if collect_cache else None)
-        (x, aux), ys = jax.lax.scan(jax.checkpoint(body), init, stack_params,
-                                    unroll=True if unroll else 1)
-    else:
-        def body(carry, xs):
-            sp, lc = xs
-            h, aux, ncs = apply_layer(carry[0], carry[1], sp, lc)
-            return (h, aux), ncs
-        (x, aux), ys = jax.lax.scan(jax.checkpoint(body), init,
-                                    (stack_params, caches),
-                                    unroll=True if unroll else 1)
+        return (h, aux), (ncs if collect_cache else None)
+    (x, aux), ys = jax.lax.scan(jax.checkpoint(body), init, stack_params,
+                                unroll=unroll)
     return x, ys, aux
 
 
@@ -184,7 +228,10 @@ def loss_fn(cfg, p: Params, batch: Dict[str, jnp.ndarray]
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg, batch: int, max_seq: int) -> List[Any]:
-    """Decode cache: one stacked pytree per stack (leading dim = #layers)."""
+    """Decode cache: one stacked pytree per stack (leading dim = #layers).
+
+    ``decode_step`` writes one K/V row per layer into it, and each layer's
+    SSM state and conv tails; in place where its caller donates it."""
     dtype = dtype_of(cfg.param_dtype)
     caches = []
     for kinds, count in layer_plan(cfg):

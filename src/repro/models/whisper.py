@@ -95,8 +95,8 @@ def init_cache(cfg, batch: int, max_seq: int) -> List[Any]:
 
     def one(_):
         c = init_block_cache(cfg, "decoder", batch, max_seq, dtype)
-        c["cross_k"] = jnp.zeros((batch, cfg.enc_seq, KV, hd), dtype)
-        c["cross_v"] = jnp.zeros((batch, cfg.enc_seq, KV, hd), dtype)
+        c["cross_k"] = jnp.zeros((batch, cfg.enc_seq, KV * hd), dtype)
+        c["cross_v"] = jnp.zeros((batch, cfg.enc_seq, KV * hd), dtype)
         return {"b0": c}
 
     return [jax.vmap(one)(jnp.arange(cfg.n_layers))]

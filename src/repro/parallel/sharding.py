@@ -93,7 +93,7 @@ def _fit(mesh: Mesh, axes, dim_size: int):
 def cache_spec_for(leaf_path: str, shape, mesh: Mesh) -> P:
     """Sharding for decode-cache leaves.
 
-    KV caches (L, B, S, KV, hd): batch over data axes, *sequence over model*
+    KV caches (L, B, S, KV*hd): batch over data axes, *sequence over model*
     (split-KV).  SSM states (L, B, H, P, N): heads over model.  Conv tails
     and cross-attention caches: batch only.  Leading dim = stacked layers
     (unsharded).  Dims that don't divide the mesh axes stay replicated.
@@ -102,7 +102,7 @@ def cache_spec_for(leaf_path: str, shape, mesh: Mesh) -> P:
     ndim = len(shape)
     if leaf_path in ("k", "v"):
         return P(None, _fit(mesh, ba, shape[1]),
-                 _fit(mesh, "model", shape[2]), None, None)
+                 _fit(mesh, "model", shape[2]), None)
     if leaf_path == "state":
         return P(None, _fit(mesh, ba, shape[1]),
                  _fit(mesh, "model", shape[2]), None, None)

@@ -111,9 +111,12 @@ class ServeEngine:
     """Single-host engine over a ModelBundle (works meshed or unmeshed).
 
     Prefill and decode are each one jitted step that ends in the greedy
-    token.  Under an installed mesh context (``set_mesh_context``) the
-    steps pin the decode cache to its split-KV sharding and the token to
-    replicated, so every decode step reuses one compiled program.
+    token.  The decode step takes its cache donated: it writes each layer's
+    new K/V row into the cache in place and hands the same buffers back, so
+    the cache passed to it is deleted.  Under an installed mesh context
+    (``set_mesh_context``) the steps pin the decode cache to its split-KV
+    sharding and the token to replicated, so every decode step reuses one
+    compiled program.
     """
 
     def __init__(self, bundle, params, ecfg: EngineConfig):
@@ -122,14 +125,17 @@ class ServeEngine:
         self.ecfg = ecfg
         self.cfg = bundle.cfg
         self._shardings = self._step_shardings()
-        self._decode = jax.jit(self._decode_step)
+        self._decode = jax.jit(self._decode_step, donate_argnums=(1,))
         self._prefill = jax.jit(self._prefill_step)
         self.queue: List[Request] = []
         # prefill_s / decode_s: host wall time of the device work, each
         # window closed by a device sync; tokens_out counts each request's
         # prefill token, so (tokens_out - admitted) / (decode_steps x
         # batch_size) is the share of decode slots that emitted a token;
-        # compiles: compiles (or compile-cache loads) of the two steps
+        # compiles: compiles (or compile-cache loads) of the two steps;
+        # decode_aliased_bytes: the compiled decode step's output bytes that
+        # reuse its donated input (the whole cache when the donation takes),
+        # set by ``compile`` (None where the backend does not report it)
         self.stats: Dict[str, float] = {"prefills": 0, "decode_steps": 0,
                                         "tokens_out": 0, "admitted": 0,
                                         "compiles": 0, "prefill_s": 0.0,
@@ -180,9 +186,13 @@ class ServeEngine:
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
                 (tok, caches))
         t0 = time.perf_counter()
-        self._decode.lower(self.params, caches, tok,
-                           jax.ShapeDtypeStruct((), jnp.int32)).compile()
+        decode = self._decode.lower(self.params, caches, tok,
+                                    jax.ShapeDtypeStruct((), jnp.int32)
+                                    ).compile()
         out["decode_s"] = time.perf_counter() - t0
+        mem = decode.memory_analysis()
+        self.stats["decode_aliased_bytes"] = getattr(
+            mem, "alias_size_in_bytes", None)
         self.stats["compiles"] += _thread_compiles() - compiles0
         return out
 

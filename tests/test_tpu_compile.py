@@ -91,3 +91,38 @@ def test_qwen2_0_5b_serving_steps_compile_for_v5e(one_chip):
                          EngineConfig(batch_size=8, max_seq=1024))
     seconds = engine.compile(prompt_len=512)
     assert seconds["prefill_s"] > 0 and seconds["decode_s"] > 0
+
+
+def test_qwen2_0_5b_decode_writes_its_cache_in_place_for_v5e(one_chip):
+    """The served decode step at the chat cell's shape (batch 128, max_seq
+    1536) hands the whole donated cache back in place: the only output
+    bytes not aliased to an input are those of a step that returns the
+    token and the cache untouched, and its scratch holds no copy of the
+    cache, nor of one layer's K and V."""
+    from repro.models import build
+    from repro.serve import EngineConfig, ServeEngine
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    bundle = build(get_config("qwen2_0_5b"))
+    engine = ServeEngine(bundle, on_chip(jax.eval_shape(
+        bundle.init, jax.random.PRNGKey(0))),
+        EngineConfig(batch_size=128, max_seq=1536))
+    engine.compile(prompt_len=16)
+    caches = jax.eval_shape(lambda: bundle.init_cache(128, 1536))
+    cache_bytes = sum(c.size * c.dtype.itemsize
+                      for c in jax.tree.leaves(caches))
+    assert engine.stats["decode_aliased_bytes"] == cache_bytes == 2_415_919_104
+
+    tok = jax.ShapeDtypeStruct((128, 1), jnp.int32)
+    step = engine._decode.lower(engine.params, caches, tok,
+                                jax.ShapeDtypeStruct((), jnp.int32)
+                                ).compile().memory_analysis()
+    bare = jax.jit(lambda c, t: (t, c), donate_argnums=(0,)).lower(
+        on_chip(caches), on_chip(tok)).compile().memory_analysis()
+    assert bare.alias_size_in_bytes == cache_bytes
+    assert step.output_size_in_bytes - step.alias_size_in_bytes == \
+        bare.output_size_in_bytes - bare.alias_size_in_bytes
+    assert step.temp_size_in_bytes < cache_bytes // 24
