@@ -1,12 +1,18 @@
 """One run of one cell: set-up, the measured window, the check.
 
+What depends on the architecture comes from the configuration file's
+module, ``archs/<model_type>.py`` (``archs.for_model``).  The harness
+itself reads three keys of every configuration file: ``model_type``,
+``vocab_size`` (the traffic's token range) and ``check.max_logit_gap``
+(the limit of ``correct``).
+
 Set-up builds the engine with the program's launcher
 (``repro.launch.serve.make_engine``, weights made on the device from the
-seed), then puts the q/k/v biases and norm scales that the reference draws
-from the seed (``reference.norms_and_biases``) into its weights: the
-program's initializer leaves them at 0 and 1, where dropping them would go
-unseen.  Set-up then compiles the cell's one prefill and one decode shape
-(``engine.compile``) and serves one warm-up batch of the cell's shape.
+seed), then puts into its weights the leaves that the program's
+initializer leaves at 0 and 1, drawn from the seed by the architecture's
+reference (``seed_leaves``): dropping them would go unseen.  Set-up then
+compiles the cell's one prefill and one decode shape (``engine.compile``)
+and serves one warm-up batch of the cell's shape.
 
 The window drives the public ``engine.submit`` / ``engine.run`` and does
 not batch for the engine: a submitter thread submits each request at its
@@ -17,8 +23,9 @@ engine appends: each token's arrival on the host.  After ``seconds``
 nothing more is submitted and every request submitted is served to its
 end.
 
-The check teacher-forces a sample of the served requests through the plain
-reference (``reference.py``) once the program's state is freed.
+The check teacher-forces a sample of the served requests through the
+architecture's plain reference (``served_gaps``) once the program's state
+is freed.
 """
 from __future__ import annotations
 
@@ -35,10 +42,10 @@ from typing import Optional
 
 import numpy as np
 
-import reference
-import xplane
+import archs
 import traffic
 import work
+import xplane
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -127,7 +134,7 @@ class Served:
 class Run:
     """What a metric reader reads (``chipbench/metrics/<name>.py``)."""
     cell: Cell
-    shape: work.Shape
+    shape: object            # the architecture's work counts
     setup_s: float
     t0: float                # host clock of the window's start
     window_s: float
@@ -151,54 +158,17 @@ class Run:
 # set-up
 # ---------------------------------------------------------------------------
 
-def program_config(model: dict):
-    """The program's config for a configuration file, every published size
-    set from the file."""
-    from repro.configs import get_config
-    d, h = model["hidden_size"], model["num_attention_heads"]
-    cfg = dataclasses.replace(
-        get_config(model["program"]["arch"]),
-        n_layers=model["num_hidden_layers"], d_model=d, n_heads=h,
-        n_kv_heads=model["num_key_value_heads"],
-        head_dim=model.get("head_dim", d // h),
-        d_ff=model["intermediate_size"], vocab_size=model["vocab_size"],
-        rope_theta=float(model["rope_theta"]),
-        rms_eps=float(model["rms_norm_eps"]),
-        tie_embeddings=bool(model["tie_word_embeddings"]),
-        qkv_bias=bool(model["program"]["qkv_bias"]),
-        sliding_window=(model["sliding_window"]
-                        if model["use_sliding_window"] else 0),
-        param_dtype=model["torch_dtype"], compute_dtype=model["torch_dtype"])
-    if (cfg.family, cfg.mlp, cfg.norm, cfg.qk_norm) != (
-            "dense", "swiglu", "rmsnorm", False):
-        raise ValueError(f"{cfg.name}: not a Qwen2-style dense decoder")
-    return cfg
-
-
 def build_engine(cell: Cell, seed: int):
-    """The program's launcher's engine, with the reference's seeded q/k/v
-    biases and norm scales in its weights."""
+    """The program's launcher's engine, with the seeded leaves of the
+    architecture's reference in its weights."""
     import jax
     from repro.launch.serve import make_engine
-    cfg = program_config(cell.model)
-    engine = make_engine(cfg, batch_size=cell.traffic["batch_size"],
+    arch = archs.for_model(cell.model)
+    engine = make_engine(arch.program_config(cell.model),
+                         batch_size=cell.traffic["batch_size"],
                          max_seq=cell.traffic["max_seq"], seed=seed)
-    p = engine.params
-    (stack,) = p["stacks"]
-    block = stack["b0"]
-    x = reference.norms_and_biases(cell.model, seed)
-    leaves = {("attn", "bq"): x["bq"], ("attn", "bk"): x["bk"],
-              ("attn", "bv"): x["bv"], ("ln1", "scale"): x["ln1"],
-              ("ln2", "scale"): x["ln2"]}
-    for (group, name), value in leaves.items():
-        old = block[group][name]
-        if old.shape != value.shape or old.dtype != value.dtype:
-            raise ValueError(f"{group}.{name}: program {old.shape} "
-                             f"{old.dtype}, reference {value.shape} "
-                             f"{value.dtype}")
-        block[group][name] = value
-    p["final_norm"]["scale"] = x["final"]
-    jax.block_until_ready(p)
+    arch.seed_leaves(engine.params, cell.model, seed)
+    jax.block_until_ready(engine.params)
     return engine
 
 
@@ -347,7 +317,7 @@ def check_sample(served: list, k: int, seed: int) -> list:
 def check(cell: Cell, seed: int, served: list, control: bool = False
           ) -> dict:
     sample = check_sample(served, cell.traffic["check_requests"], seed)
-    gaps = reference.served_gaps(
+    gaps = archs.for_model(cell.model).served_gaps(
         cell.model, seed, [s.prompt for s in sample],
         [list(s.req.out_tokens) for s in sample],
         cell.traffic["output"]["max"], control=control)
@@ -367,6 +337,14 @@ def device_info(devices) -> dict:
             "count": len(jax.devices()), "memory_peak_bytes": max(peaks)}
 
 
+def _window_change(after, before):
+    """A counter's change over the window; a value that is not a number,
+    such as a size the backend does not report (None), as it stands."""
+    if isinstance(after, (int, float)) and isinstance(before, (int, float)):
+        return after - before
+    return after
+
+
 def serve_window(cell: Cell, seed: int, seconds: float, devices,
                  t_start: float, trace_dir: Optional[Path] = None):
     """Set-up and the window.  Returns (Run, device info, trace file or
@@ -381,13 +359,14 @@ def serve_window(cell: Cell, seed: int, seconds: float, devices,
     prof = Profiler(trace_dir, t0, seconds) if trace_dir else None
     served = drive(engine, cell, seed, seconds, t0)
     trace_file = prof.file() if prof else None
-    stats = {k: engine.stats[k] - before[k] for k in before}
+    stats = {k: _window_change(engine.stats[k], v)
+             for k, v in before.items()}
     info = device_info(devices)
     del engine
     gc.collect()
-    run = Run(cell=cell, shape=work.Shape.of(cell.model), setup_s=setup_s,
-              t0=t0, window_s=seconds, served=served, stats=stats,
-              device_kind=info["kind"])
+    run = Run(cell=cell, shape=archs.for_model(cell.model).shape(cell.model),
+              setup_s=setup_s, t0=t0, window_s=seconds, served=served,
+              stats=stats, device_kind=info["kind"])
     return run, info, trace_file
 
 
@@ -440,4 +419,5 @@ def info_line(run: Run) -> dict:
             "generator_late_p99_ms": float(np.percentile(late, 99) * 1e3)
             if late else None,
             "decode_steps": run.stats.get("decode_steps"),
-            "prefills": run.stats.get("prefills")}
+            "prefills": run.stats.get("prefills"),
+            "compiles": run.stats.get("compiles")}
