@@ -12,6 +12,10 @@ import importlib
 from typing import Dict, Optional, Tuple
 
 
+# block kinds that hold a Mamba-2 (SSD) mixer
+SSM_KINDS = ("ssm", "hybrid", "mamba")
+
+
 def round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
@@ -56,6 +60,19 @@ class ModelConfig:
     # --- hybrid (hymba): parallel attn+ssm heads ---
     sliding_window: int = 0       # 0 = full attention
 
+    # --- hybrid of layers (granite-4.0-h): one block kind per layer ---
+    layer_kinds: Tuple[str, ...] = ()   # "mamba" | "dense"; () = by family
+                                        # (see ``block_kinds``)
+    rope: bool = True             # False: no rotary embedding (granite:
+                                  # NoPE; whisper: learned positions)
+
+    # --- muP multipliers (granite-4.0-h) ---
+    embedding_multiplier: float = 1.0   # token embeddings times this
+    residual_multiplier: float = 1.0    # every residual branch times this
+    attention_multiplier: Optional[float] = None  # softmax scale; None:
+                                                  # 1/sqrt(head_dim)
+    logits_scaling: float = 1.0         # logits divided by this
+
     # --- enc-dec (whisper) ---
     n_enc_layers: int = 0
     enc_seq: int = 0              # encoder frames (stub frontend output)
@@ -95,20 +112,32 @@ class ModelConfig:
     def attention_layers(self) -> int:
         return 0 if self.family == "ssm" else self.n_layers
 
+    def block_kinds(self) -> Tuple[str, ...]:
+        """The block kind of every layer of the LM stack, in order: the
+        config's ``layer_kinds`` where it lists them, else by family."""
+        if self.layer_kinds:
+            return self.layer_kinds
+        if self.family == "moe":
+            if self.moe_interleave > 1:
+                pairs = self.n_layers // self.moe_interleave
+                return (("dense",) * (self.moe_interleave - 1)
+                        + ("moe",)) * pairs
+            return (("dense",) * self.first_k_dense
+                    + ("moe",) * (self.n_layers - self.first_k_dense))
+        kind = {"vlm": "dense", "encdec": "decoder"}.get(self.family,
+                                                          self.family)
+        return (kind,) * self.n_layers
+
     def param_count(self) -> int:
         """Analytic parameter count (embedding included once)."""
-        d, L = self.d_model, self.n_layers
+        d = self.d_model
         hd, H, KV = self.head_dim_, self.n_heads, self.n_kv_heads
         n = self.padded_vocab * d  # embedding
         if not self.tie_embeddings:
             n += self.padded_vocab * d
         attn = d * H * hd + 2 * d * KV * hd + H * hd * d
-        if self.family == "ssm":
-            attn = 0
-        ssm = 0
-        if self.family in ("ssm", "hybrid"):
-            di, ns, nh = self.d_inner, self.ssm_state, self.ssm_heads
-            ssm = d * (2 * di + 2 * ns + nh) + di * d + 3 * nh
+        di, ns, nh = self.d_inner, self.ssm_state, self.ssm_heads
+        ssm = d * (2 * di + 2 * ns + nh) + di * d + 3 * nh
         if self.family == "moe":
             shared = self.n_shared_experts * 3 * d * self.moe_d_ff
             routed = self.n_experts * 3 * d * self.moe_d_ff
@@ -119,13 +148,9 @@ class ModelConfig:
             n += n_dense * (attn + dense_ff)
             return n
         ff = 3 * d * self.d_ff if self.mlp == "swiglu" else 2 * d * self.d_ff
-        if self.family == "ssm":
-            per_layer = ssm
-        elif self.family == "hybrid":
-            per_layer = attn + ssm + ff
-        else:
-            per_layer = attn + ff
-        n += L * per_layer
+        per_kind = {"dense": attn + ff, "decoder": attn + ff, "ssm": ssm,
+                    "mamba": ssm + ff, "hybrid": attn + ssm + ff}
+        n += sum(per_kind[k] for k in self.block_kinds())
         if self.n_enc_layers:
             n += self.n_enc_layers * (attn + ff)     # encoder stack
             n += self.n_layers * (attn := attn)      # cross-attn in decoder
@@ -170,8 +195,11 @@ def reduce_for_smoke(cfg: "ModelConfig") -> "ModelConfig":
         kw.update(n_experts=8, top_k=min(cfg.top_k, 2), moe_d_ff=32,
                   first_k_dense=min(cfg.first_k_dense, 1),
                   n_layers=2 * cfg.moe_interleave + cfg.first_k_dense)
-    if cfg.family in ("ssm", "hybrid"):
+    if set(cfg.block_kinds()) & set(SSM_KINDS):
         kw.update(ssm_headdim=8, ssm_state=8, ssm_chunk=8)
+    if cfg.layer_kinds:     # one layer of each kind, in order of appearance
+        kinds = tuple(dict.fromkeys(cfg.layer_kinds))
+        kw.update(layer_kinds=kinds, n_layers=len(kinds))
     if cfg.sliding_window:
         kw.update(sliding_window=16)
     if cfg.family == "encdec":
@@ -192,12 +220,14 @@ ARCH_IDS = [
     "deepseek_moe_16b",
     "llama4_maverick_400b_a17b",
     "internvl2_26b",
+    "granite_4_0_h_micro",
 ]
 
 # accept both dash and underscore spellings on the CLI
 ALIASES = {a.replace("_", "-"): a for a in ARCH_IDS}
 ALIASES.update({
     "hymba-1.5b": "hymba_1_5b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     "qwen2-7b": "qwen2_7b",
     "llama3.2-1b": "llama3_2_1b",
     "qwen2-0.5b": "qwen2_0_5b",

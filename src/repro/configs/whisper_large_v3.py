@@ -12,6 +12,6 @@ CONFIG = ModelConfig(
     d_ff=5120, vocab_size=51866, head_dim=64,
     n_enc_layers=32, enc_seq=1500,
     frontend="audio_stub", frontend_seq=1500, frontend_dim=1280,
-    norm="layernorm", mlp="gelu",
+    norm="layernorm", mlp="gelu", rope=False,   # learned positions
     source="arXiv:2212.04356; unverified",
 )

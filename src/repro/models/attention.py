@@ -32,6 +32,12 @@ from .common import (Params, apply_rope, dense_init, get_mesh_context,
 NEG_INF = -1e30
 
 
+def softmax_scale(cfg, hd: int) -> float:
+    """The config's attention multiplier, else 1/sqrt(head_dim)."""
+    m = cfg.attention_multiplier
+    return 1.0 / math.sqrt(hd) if m is None else m
+
+
 def pick_chunk(s: int, target: int) -> int:
     """Largest divisor of ``s`` that is <= target (>=1)."""
     c = min(target, s)
@@ -125,7 +131,7 @@ def chunked_attention(cfg, q, k, v, q_positions, kv_positions, *,
     B, Sq, H, hd = q.shape
     Skv, KV = k.shape[1], k.shape[2]
     G = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    scale = softmax_scale(cfg, hd)
     cq = pick_chunk(Sq, q_chunk)
     qg = q.reshape(B, Sq, KV, G, hd)
 
@@ -359,7 +365,7 @@ def decode_attention(cfg, q, cache: Dict[str, jnp.ndarray],
     k, v = cache["k"], cache["v"]
     S, KV = k.shape[1], k.shape[2] // hd
     G = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    scale = softmax_scale(cfg, hd)
     own = jnp.eye(KV, dtype=jnp.float32)
     qg = jnp.einsum("bkgh,kl->bkglh", q.reshape(B, KV, G, hd).astype(
         jnp.float32), own).reshape(B, KV, G, KV * hd)

@@ -1,17 +1,20 @@
 """Transformer/SSM/hybrid/MoE blocks, composed from attention/ssd/moe.
 
-Block kinds (selected by the LM from the config family):
+Block kinds (one per layer, from ``ModelConfig.block_kinds``):
 
   dense   : norm -> attn -> +res ; norm -> mlp  -> +res
   moe     : norm -> attn -> +res ; norm -> moe  -> +res   (+ shared experts)
   ssm     : norm -> ssd  -> +res                           (mamba2: no FFN)
+  mamba   : norm -> ssd  -> +res ; norm -> mlp  -> +res   (granite-4.0-h)
   hybrid  : norm -> (attn || ssd) -> +res ; norm -> mlp -> +res   (hymba)
   encoder : norm -> bidir attn -> +res ; norm -> mlp -> +res      (whisper)
   decoder : norm -> causal attn -> +res ; norm -> cross-attn -> +res ;
             norm -> mlp -> +res                                   (whisper)
 
-Every init returns (params, logical_axes).  Every forward threads an optional
-per-layer cache (decode) and an aux-loss accumulator (MoE load balance).
+Every residual branch is scaled by ``cfg.residual_multiplier`` (granite's
+muP; 1 elsewhere).  Every init returns (params, logical_axes).  Every
+forward threads an optional per-layer cache (decode) and an aux-loss
+accumulator (MoE load balance).
 """
 from __future__ import annotations
 
@@ -66,6 +69,12 @@ def mlp_forward(cfg, p: Params, x: jnp.ndarray) -> jnp.ndarray:
 # blocks
 # ---------------------------------------------------------------------------
 
+def residual(cfg, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """``x`` plus the branch ``y`` scaled by the residual multiplier."""
+    m = cfg.residual_multiplier
+    return x + (y if m == 1.0 else y * m)
+
+
 def block_init(cfg, key, dtype, kind: str) -> Tuple[Params, Dict]:
     ks = jax.random.split(key, 8)
     p: Params = {}
@@ -75,13 +84,13 @@ def block_init(cfg, key, dtype, kind: str) -> Tuple[Params, Dict]:
         p["attn"], ax["attn"] = attention_init(cfg, ks[0], dtype)
     if kind == "hybrid":
         p["ssd"], ax["ssd"] = ssd_init(cfg, ks[1], dtype)
-    if kind == "ssm":
+    if kind in ("ssm", "mamba"):
         p["ln1"], ax["ln1"] = norm_init(cfg, cfg.d_model, dtype)
         p["ssd"], ax["ssd"] = ssd_init(cfg, ks[1], dtype)
     if kind == "decoder":
         p["ln_cross"], ax["ln_cross"] = norm_init(cfg, cfg.d_model, dtype)
         p["cross"], ax["cross"] = attention_init(cfg, ks[2], dtype, cross=True)
-    if kind in ("dense", "hybrid", "encoder", "decoder"):
+    if kind in ("dense", "hybrid", "encoder", "decoder", "mamba"):
         p["ln2"], ax["ln2"] = norm_init(cfg, cfg.d_model, dtype)
         p["mlp"], ax["mlp"] = mlp_init(cfg, ks[3], dtype)
     if kind == "moe":
@@ -106,17 +115,20 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
     """
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
-    use_rope = cfg.norm != "layernorm"  # whisper uses learned pos embeds
     window = cfg.sliding_window if window_override is None else window_override
     decoding = cache is not None and x.shape[1] == 1
 
-    if kind == "ssm":
+    if kind in ("ssm", "mamba"):
         h = apply_norm(cfg, x, p["ln1"])
         if decoding:
             y, new_cache = ssd_decode_step(cfg, p["ssd"], h, cache)
         else:
             y, new_cache = ssd_forward(cfg, p["ssd"], h, return_state=True)
-        return x + y, new_cache, aux
+        x = residual(cfg, x, y)
+        if kind == "ssm":
+            return x, new_cache, aux
+        h = apply_norm(cfg, x, p["ln2"])
+        return residual(cfg, x, mlp_forward(cfg, p["mlp"], h)), new_cache, aux
 
     # --- attention sub-block ---
     h = apply_norm(cfg, x, p["ln1"])
@@ -125,12 +137,12 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
         attn_cache = {"k": cache["k"], "v": cache["v"]}
         y_attn, kv = attention_forward(
             cfg, p["attn"], h, causal=causal, window=window,
-            use_rope=use_rope, cache=attn_cache, cache_pos=cache_pos)
+            use_rope=cfg.rope, cache=attn_cache, cache_pos=cache_pos)
         new_cache.update(kv)
     else:
         y_attn, kv = attention_forward(
             cfg, p["attn"], h, causal=causal, window=window,
-            use_rope=use_rope)
+            use_rope=cfg.rope)
         if kv is not None:
             new_cache["k"], new_cache["v"] = map(fuse_heads, kv)
 
@@ -144,7 +156,7 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
             new_cache.update(ssd_new)
         # hymba: fuse the parallel attention and SSM head outputs
         y_attn = 0.5 * (y_attn + y_ssd)
-    x = x + y_attn
+    x = residual(cfg, x, y_attn)
 
     if kind == "decoder":
         h = apply_norm(cfg, x, p["ln_cross"])
@@ -159,7 +171,7 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
             if ckv is not None:
                 new_cache["cross_k"], new_cache["cross_v"] = map(
                     fuse_heads, ckv)
-        x = x + y_cross
+        x = residual(cfg, x, y_cross)
 
     # --- FFN sub-block ---
     h = apply_norm(cfg, x, p["ln2"])
@@ -169,7 +181,7 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
                              data_spec=data_spec, model_axis=model_axis)
     else:
         y = mlp_forward(cfg, p["mlp"], h)
-    return x + y, new_cache, aux
+    return residual(cfg, x, y), new_cache, aux
 
 
 def init_block_cache(cfg, kind: str, batch: int, max_seq: int, dtype) -> Dict:
@@ -177,6 +189,6 @@ def init_block_cache(cfg, kind: str, batch: int, max_seq: int, dtype) -> Dict:
     c: Dict[str, Any] = {}
     if kind in ("dense", "moe", "hybrid", "decoder", "encoder"):
         c.update(init_kv_cache(cfg, batch, max_seq, dtype))
-    if kind in ("ssm", "hybrid"):
+    if kind in ("ssm", "hybrid", "mamba"):
         c.update(init_ssd_cache(cfg, batch, dtype))
     return c

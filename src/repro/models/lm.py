@@ -42,24 +42,24 @@ def write_layer(cache: Dict[str, jnp.ndarray], layer,
 
 
 def layer_plan(cfg) -> List[Tuple[Tuple[str, ...], int]]:
-    """[(kinds-per-scan-step, count), ...] — homogeneous scan stacks."""
-    if cfg.family in ("dense", "vlm"):
-        return [(("dense",), cfg.n_layers)]
-    if cfg.family == "ssm":
-        return [(("ssm",), cfg.n_layers)]
-    if cfg.family == "hybrid":
-        return [(("hybrid",), cfg.n_layers)]
-    if cfg.family == "moe":
-        plan: List[Tuple[Tuple[str, ...], int]] = []
-        if cfg.moe_interleave > 1:
-            pairs = cfg.n_layers // cfg.moe_interleave
-            kinds = tuple(["dense"] * (cfg.moe_interleave - 1) + ["moe"])
-            return [(kinds, pairs)]
-        if cfg.first_k_dense:
-            plan.append((("dense",), cfg.first_k_dense))
-        plan.append((("moe",), cfg.n_layers - cfg.first_k_dense))
-        return plan
-    raise ValueError(f"layer_plan: unhandled family {cfg.family}")
+    """[(kinds-per-scan-step, count), ...] — homogeneous scan stacks.
+
+    The layer kinds' shortest period, scanned as one stack, where they
+    repeat it more than once (uniform stacks, llama4's interleave,
+    granite's mamba x5, attention, mamba x4); else a stack per run of
+    consecutive layers of one kind (deepseek's leading dense layer)."""
+    kinds = list(cfg.block_kinds())
+    n = len(kinds)
+    for p in range(1, n // 2 + 1):
+        if n % p == 0 and kinds == kinds[:p] * (n // p):
+            return [(tuple(kinds[:p]), n // p)]
+    plan: List[Tuple[Tuple[str, ...], int]] = []
+    for k in kinds:
+        if plan and plan[-1][0] == (k,):
+            plan[-1] = ((k,), plan[-1][1] + 1)
+        else:
+            plan.append(((k,), 1))
+    return plan
 
 
 def _stack_init(cfg, key, dtype, kinds: Tuple[str, ...], count: int):
@@ -111,12 +111,16 @@ def init_params(cfg, key) -> Tuple[Params, Dict]:
 
 def embed_tokens(cfg, p: Params, tokens: jnp.ndarray) -> jnp.ndarray:
     x = jnp.take(p["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     return with_logical_constraint(x, "batch", None, None)
 
 
 def unembed(cfg, p: Params, x: jnp.ndarray) -> jnp.ndarray:
     w = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", x, w)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return with_logical_constraint(logits, "batch", None, "vocab_act")
 
 

@@ -55,14 +55,20 @@ def ssd_init(cfg, key, dtype) -> Tuple[Params, Dict]:
         "norm": ("heads",),
         "w_out": ("heads", "embed"),
     }
+    # conv biases, zero as Mamba-2's are (published checkpoints train them)
+    p["conv_x_bias"] = jnp.zeros((di,), dtype)
+    p["conv_BC_bias"] = jnp.zeros((2 * G * N,), dtype)
+    ax["conv_x_bias"], ax["conv_BC_bias"] = ("heads",), (None,)
     return p, ax
 
 
-def _causal_conv(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """Depthwise causal conv along seq: x (B,S,C), w (K,C)."""
+def _causal_conv(x: jnp.ndarray, w: jnp.ndarray,
+                 b: jnp.ndarray) -> jnp.ndarray:
+    """Depthwise causal conv along seq, then SiLU: x (B,S,C), w (K,C),
+    bias b (C,)."""
     K = w.shape[0]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    out = jnp.zeros_like(x, dtype=jnp.float32)
+    out = jnp.broadcast_to(b.astype(jnp.float32), x.shape)
     for i in range(K):
         out = out + xp[:, i:i + x.shape[1], :].astype(jnp.float32) * \
             w[i].astype(jnp.float32)
@@ -157,8 +163,8 @@ def ssd_forward(cfg, p: Params, x: jnp.ndarray, *,
                         jnp.concatenate([p["w_B"], p["w_C"]], axis=1))
     dt = jnp.einsum("bsd,dh->bsh", x, p["w_dt"])
 
-    xin = _causal_conv(xin_pre, p["conv_x"])
-    BC = _causal_conv(BC_pre, p["conv_BC"])
+    xin = _causal_conv(xin_pre, p["conv_x"], p["conv_x_bias"])
+    BC = _causal_conv(BC_pre, p["conv_BC"], p["conv_BC_bias"])
     Bm, Cm = jnp.split(BC, 2, axis=-1)
 
     Bsz, S = x.shape[0], x.shape[1]
@@ -199,11 +205,13 @@ def init_ssd_cache(cfg, batch: int, dtype) -> Dict[str, jnp.ndarray]:
     }
 
 
-def _conv_step(buf: jnp.ndarray, xt: jnp.ndarray, w: jnp.ndarray):
-    """buf (B,K-1,C) holds previous inputs; xt (B,C).  Returns (y, new_buf)."""
+def _conv_step(buf: jnp.ndarray, xt: jnp.ndarray, w: jnp.ndarray,
+               b: jnp.ndarray):
+    """buf (B,K-1,C) holds previous inputs; xt (B,C); bias b (C,).
+    Returns (y, new_buf)."""
     full = jnp.concatenate([buf, xt[:, None, :]], axis=1)   # (B,K,C)
     y = jnp.einsum("bkc,kc->bc", full.astype(jnp.float32),
-                   w.astype(jnp.float32))
+                   w.astype(jnp.float32)) + b.astype(jnp.float32)
     return jax.nn.silu(y).astype(xt.dtype), full[:, 1:, :]
 
 
@@ -216,8 +224,10 @@ def ssd_decode_step(cfg, p: Params, x: jnp.ndarray, cache: Dict):
     BC = xt @ jnp.concatenate([p["w_B"], p["w_C"]], axis=1)
     dt = xt @ p["w_dt"]
 
-    xin, conv_x = _conv_step(cache["conv_x"], xin, p["conv_x"])
-    BC, conv_BC = _conv_step(cache["conv_BC"], BC, p["conv_BC"])
+    xin, conv_x = _conv_step(cache["conv_x"], xin, p["conv_x"],
+                             p["conv_x_bias"])
+    BC, conv_BC = _conv_step(cache["conv_BC"], BC, p["conv_BC"],
+                             p["conv_BC_bias"])
     Bm, Cm = jnp.split(BC, 2, axis=-1)                       # (B,N) each
 
     A = -jnp.exp(p["A_log"])

@@ -74,9 +74,9 @@ def _layer_flops_full(cfg: ModelConfig, B: int, S: int, kind: str,
         f += 2.0 * B * S * d * H * hd + 2.0 * B * cfg.enc_seq * d * 2 * KV * hd
         f += 2.0 * B * S * H * hd * d
         f += _attn_core_flops(B, S, cfg.enc_seq, H, hd)
-    if kind in ("ssm", "hybrid"):
+    if kind in ("ssm", "hybrid", "mamba"):
         f += _ssd_flops(cfg, B, S)
-    if kind in ("dense", "hybrid", "encoder", "decoder"):
+    if kind in ("dense", "hybrid", "encoder", "decoder", "mamba"):
         n_mats = 3 if cfg.mlp == "swiglu" else 2
         f += 2.0 * B * S * d * cfg.d_ff * n_mats
     if kind == "moe":
@@ -100,11 +100,11 @@ def _layer_flops_decode(cfg: ModelConfig, B: int, S_cache: int,
     if kind == "decoder":
         f += 2.0 * B * d * H * hd + 2.0 * B * H * hd * d
         f += 2.0 * 2.0 * B * H * cfg.enc_seq * hd
-    if kind in ("ssm", "hybrid"):
+    if kind in ("ssm", "hybrid", "mamba"):
         di, P, N, Hs = cfg.d_inner, cfg.ssm_headdim, cfg.ssm_state, cfg.ssm_heads
         f += 2.0 * B * d * (2 * di + 2 * N + Hs) + 2.0 * B * di * d
         f += 2.0 * B * Hs * P * N * 2
-    if kind in ("dense", "hybrid", "decoder"):
+    if kind in ("dense", "hybrid", "decoder", "mamba"):
         n_mats = 3 if cfg.mlp == "swiglu" else 2
         f += 2.0 * B * d * cfg.d_ff * n_mats
     if kind == "moe":
@@ -165,7 +165,7 @@ def step_bytes(cfg: ModelConfig, shape: ShapeSpec, *,
                 total += count * B * eff * 2 * KV * hd * DT   # cache read
             if kind == "decoder":
                 total += count * B * cfg.enc_seq * 2 * KV * hd * DT
-            if kind in ("ssm", "hybrid"):
+            if kind in ("ssm", "hybrid", "mamba"):
                 total += count * B * cfg.ssm_heads * cfg.ssm_headdim * \
                     cfg.ssm_state * F32 * 2                    # state r/w
         total += B * cfg.padded_vocab * DT                     # logits
@@ -177,7 +177,7 @@ def step_bytes(cfg: ModelConfig, shape: ShapeSpec, *,
     for kind, count in _layer_kinds(cfg):
         Sk = cfg.enc_seq if kind == "encoder" else S
         width = (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_dim_ \
-            if kind != "ssm" else 2 * cfg.d_inner
+            if kind not in ("ssm", "mamba") else 2 * cfg.d_inner
         qkv_stream += count * B * Sk * width * DT * 2          # r + w
     logits = B * S * cfg.padded_vocab * F32
     if shape.kind == "prefill":

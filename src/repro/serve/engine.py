@@ -17,6 +17,7 @@ counts requests admitted and compiles of the two steps, and each
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -27,6 +28,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.common import get_mesh_context
+from ..models.lm import STATES
 from ..parallel import sharding as shd
 
 # host spans of the serving loop.  Each is opened and closed inside one
@@ -107,6 +109,17 @@ def seed_decode_cache(bundle, prefill_caches, batch_size: int, max_seq: int):
     return out
 
 
+def cache_bytes(caches) -> Dict[str, int]:
+    """Bytes of a decode cache (arrays or shapes): its K/V leaves (``kv``)
+    and the leaves a decode step rewrites whole (``state``: SSM states and
+    conv tails)."""
+    out = {"kv": 0, "state": 0}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(caches):
+        kind = "state" if getattr(path[-1], "key", None) in STATES else "kv"
+        out[kind] += math.prod(leaf.shape) * jnp.dtype(leaf.dtype).itemsize
+    return out
+
+
 class ServeEngine:
     """Single-host engine over a ModelBundle (works meshed or unmeshed).
 
@@ -135,7 +148,9 @@ class ServeEngine:
         # compiles: compiles (or compile-cache loads) of the two steps;
         # decode_aliased_bytes: the compiled decode step's output bytes that
         # reuse its donated input (the whole cache when the donation takes),
-        # set by ``compile`` (None where the backend does not report it)
+        # set by ``compile`` (None where the backend does not report it);
+        # cache_bytes: the decode cache's bytes, {"kv": K/V leaves,
+        # "state": the leaves a step rewrites whole}, set by ``compile``
         self.stats: Dict[str, float] = {"prefills": 0, "decode_steps": 0,
                                         "tokens_out": 0, "admitted": 0,
                                         "compiles": 0, "prefill_s": 0.0,
@@ -190,6 +205,7 @@ class ServeEngine:
                                     jax.ShapeDtypeStruct((), jnp.int32)
                                     ).compile()
         out["decode_s"] = time.perf_counter() - t0
+        self.stats["cache_bytes"] = cache_bytes(caches)
         mem = decode.memory_analysis()
         self.stats["decode_aliased_bytes"] = getattr(
             mem, "alias_size_in_bytes", None)

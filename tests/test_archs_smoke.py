@@ -52,7 +52,7 @@ def test_forward_and_train_step(arch):
 
 @pytest.mark.parametrize("arch", ["llama3_2_1b", "qwen3_4b", "mamba2_1_3b",
                                   "hymba_1_5b", "whisper_large_v3",
-                                  "deepseek_moe_16b"])
+                                  "deepseek_moe_16b", "granite_4_0_h_micro"])
 def test_prefill_decode_consistency(arch):
     """Next-token logits from (prefill -> decode_step) must match the full
     forward at the same position — the KV-cache/state plumbing invariant."""
@@ -102,6 +102,7 @@ def test_param_counts_match_published():
         "qwen2_7b": 7.6e9, "llama3_2_1b": 1.24e9, "qwen2_0_5b": 0.49e9,
         "qwen3_4b": 4.4e9, "mamba2_1_3b": 1.34e9,
         "deepseek_moe_16b": 16.4e9, "llama4_maverick_400b_a17b": 398e9,
+        "granite_4_0_h_micro": 3.19e9,
     }
     for arch, want in expected.items():
         got = get_config(arch).param_count()

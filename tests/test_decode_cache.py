@@ -69,6 +69,9 @@ CASES = {
     "hymba_hybrid_window": (lambda: small("hymba_1_5b"), False),
     "whisper_cross": (lambda: small("whisper_large_v3"), False),
     "deepseek_two_stacks": (lambda: small("deepseek_moe_16b"), False),
+    "granite_mamba_and_attention_stacks": (
+        lambda: small("granite_4_0_h_micro", n_layers=4, layer_kinds=(
+            "mamba", "mamba", "dense", "mamba")), False),
     "qwen2_split_kv_mesh": (lambda: small("qwen2_0_5b"), True),
 }
 

@@ -126,3 +126,26 @@ def test_qwen2_0_5b_decode_writes_its_cache_in_place_for_v5e(one_chip):
     assert step.output_size_in_bytes - step.alias_size_in_bytes == \
         bare.output_size_in_bytes - bare.alias_size_in_bytes
     assert step.temp_size_in_bytes < cache_bytes // 24
+
+
+def test_granite_serving_steps_compile_for_v5e(one_chip):
+    """granite_4_0_h_micro at every published width, at the chat cell's
+    shape (batch 32, 1024-token prompts, max_seq 1536): prefill and decode
+    fit one chip, and the decode step hands back its whole donated cache,
+    the K/V of its 4 attention layers and the state and conv tails of its
+    36 Mamba layers, in place."""
+    from repro.models import build
+    from repro.serve import EngineConfig, ServeEngine
+
+    bundle = build(get_config("granite_4_0_h_micro"))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(bundle.init, jax.random.PRNGKey(0)))
+    engine = ServeEngine(bundle, params,
+                         EngineConfig(batch_size=32, max_seq=1536))
+    engine.compile(prompt_len=1024)
+    # K/V: 4 x 2 x 32 x 1536 x 512 x 2 bytes; state: 36 x 32 x (64 x 64 x
+    # 128 x 4 + 3 x 4352 x 2) bytes
+    assert engine.stats["cache_bytes"] == {"kv": 402_653_184,
+                                           "state": 2_446_000_128}
+    assert engine.stats["decode_aliased_bytes"] == 2_848_653_312
