@@ -1,2 +1,3 @@
-from .ops import matmul, flash_attention, ssd_scan, decode_attention
+from .ops import (matmul, flash_attention, ssd_scan, ssd_state_update,
+                  decode_attention)
 from . import ref

@@ -13,6 +13,7 @@ import jax
 from .decode_attention import decode_attention as _decode
 from .flash_attention import flash_attention as _flash
 from .ssd_scan import ssd_scan as _ssd
+from .ssd_state import ssd_state_update as _ssd_state
 from .streamed_matmul import streamed_matmul as _matmul
 
 
@@ -40,3 +41,10 @@ def ssd_scan(x, dt, A, B, C, *, chunk=256, interpret=False):
 def decode_attention(q, k, v, length, *, block_s=512, interpret=False):
     """``length`` is traced: one compile serves every decode position."""
     return _decode(q, k, v, length, block_s=block_s, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssd_state_update(state, layer, xdt, dA, B, C, *, interpret=False):
+    """``layer`` is traced: one compile serves every layer of a stack;
+    ``state`` is updated in place where the caller donates it."""
+    return _ssd_state(state, layer, xdt, dA, B, C, interpret=interpret)

@@ -103,15 +103,19 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
                   cache: Optional[Dict] = None,
                   cache_pos: Optional[jnp.ndarray] = None,
                   enc_out: Optional[jnp.ndarray] = None,
-                  window_override: Optional[int] = None
+                  window_override: Optional[int] = None,
+                  layer: Optional[jnp.ndarray] = None
                   ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
-    """Returns (y, new_cache, aux_loss).  ``cache`` is this layer's slice.
+    """Returns (y, new_cache, aux_loss).  ``cache`` is this layer's slice,
+    but for the SSM state: the stack's whole state, of which the step
+    rewrites layer ``layer`` (``ssd_decode_step``).
 
     In decode the returned 'cache' holds what the step writes: the new
-    token's K/V row and the new SSM state + conv tails; cross-attention K/V
-    are read and not returned.  In full (train/prefill) mode it holds the
-    K/V computed for the sequence (prefill seeds the decode cache from it);
-    SSM blocks return their final state + conv tails.
+    token's K/V row, the stack's state with this layer's rewritten, and
+    the new conv tails; cross-attention K/V are read and not returned.  In
+    full (train/prefill) mode it holds the K/V computed for the sequence
+    (prefill seeds the decode cache from it); SSM blocks return their final
+    state + conv tails.
     """
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
@@ -121,7 +125,7 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
     if kind in ("ssm", "mamba"):
         h = apply_norm(cfg, x, p["ln1"])
         if decoding:
-            y, new_cache = ssd_decode_step(cfg, p["ssd"], h, cache)
+            y, new_cache = ssd_decode_step(cfg, p["ssd"], h, cache, layer)
         else:
             y, new_cache = ssd_forward(cfg, p["ssd"], h, return_state=True)
         x = residual(cfg, x, y)
@@ -149,7 +153,8 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
     if kind == "hybrid":
         if decoding:
             ssd_cache = {k: cache[k] for k in ("state", "conv_x", "conv_BC")}
-            y_ssd, ssd_new = ssd_decode_step(cfg, p["ssd"], h, ssd_cache)
+            y_ssd, ssd_new = ssd_decode_step(cfg, p["ssd"], h, ssd_cache,
+                                             layer)
             new_cache.update(ssd_new)
         else:
             y_ssd, ssd_new = ssd_forward(cfg, p["ssd"], h, return_state=True)

@@ -21,7 +21,10 @@ from .common import (Params, apply_norm, dtype_of, embed_init,
                      get_scan_unroll, norm_init, softmax_cross_entropy,
                      with_logical_constraint)
 
-# decode-cache leaves that a decode step rewrites whole, layer by layer
+# decode-cache leaves that a decode step rewrites whole, layer by layer: the
+# SSM state, which a layer's step takes for the whole stack and hands back
+# with its own layer rewritten (in place, on a TPU: ssd.update_state), and
+# the conv tails, sliced out per layer and written back
 STATES = ("state", "conv_x", "conv_BC")
 
 
@@ -146,10 +149,14 @@ def _scan_stack(cfg, stack_params, x, kinds: Tuple[str, ...], *,
     layer's slab: each layer attends its K/V slice with the new token's row
     selected in as the slice is read, and after the scan one row per layer
     is written into the stacked K/V cache.  SSM states and conv tails, which
-    a step rewrites whole, are carried through the scan and overwritten
-    layer by layer.  The K/V cache is not carried: the TPU compiler lays a
-    carried K/V cache out unlike the donated argument and copies it whole
-    into and out of the loop (compiled for a described v5e).
+    a step rewrites whole, are carried through the scan.  Each Mamba layer
+    is handed its stack's whole state with the layer index and hands it
+    back with that layer rewritten: on a TPU by one pass of a kernel that
+    writes the state where it read it (``ssd.update_state``).  Its conv
+    tails are sliced out and written back.  The K/V cache is not carried:
+    the TPU compiler lays a carried K/V cache out unlike the donated
+    argument and copies it whole into and out of the loop (compiled for a
+    described v5e).
     """
     init = (x, jnp.zeros((), jnp.float32))
     unroll = True if get_scan_unroll() else 1
@@ -165,13 +172,16 @@ def _scan_stack(cfg, stack_params, x, kinds: Tuple[str, ...], *,
             states, rows = dict(states), {}
             for i, kind in enumerate(kinds):
                 b = f"b{i}"
+                tails = {n: c for n, c in states[b].items() if n != "state"}
+                whole = {n: c for n, c in states[b].items() if n == "state"}
                 h, new, a = block_forward(
                     cfg, sp[b], h, kind,
-                    cache={**lc[b], **layer_slice(states[b], layer)},
-                    cache_pos=cache_pos, enc_out=enc_out)
+                    cache={**lc[b], **layer_slice(tails, layer), **whole},
+                    cache_pos=cache_pos, enc_out=enc_out, layer=layer)
                 aux = aux + a
-                states[b] = write_layer(states[b], layer, {
-                    n: v for n, v in new.items() if n in STATES})
+                states[b] = {**write_layer(tails, layer, {
+                    n: v for n, v in new.items() if n in tails}),
+                    **{n: new[n] for n in whole}}
                 rows[b] = {n: v for n, v in new.items() if n not in STATES}
             return (h, aux, states), rows
 
@@ -235,7 +245,8 @@ def init_cache(cfg, batch: int, max_seq: int) -> List[Any]:
     """Decode cache: one stacked pytree per stack (leading dim = #layers).
 
     ``decode_step`` writes one K/V row per layer into it, and each layer's
-    SSM state and conv tails; in place where its caller donates it."""
+    SSM state and conv tails; in place where its caller donates it, the
+    state on a TPU by the kernel that reads it (``ssd.update_state``)."""
     dtype = dtype_of(cfg.param_dtype)
     caches = []
     for kinds, count in layer_plan(cfg):

@@ -20,7 +20,9 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .common import Params, dense_init, get_scan_unroll, rmsnorm
+from ..kernels.ops import ssd_state_update
+from .common import (Params, dense_init, get_mesh_context, get_scan_unroll,
+                     rmsnorm)
 
 
 def ssd_init(cfg, key, dtype) -> Tuple[Params, Dict]:
@@ -215,8 +217,42 @@ def _conv_step(buf: jnp.ndarray, xt: jnp.ndarray, w: jnp.ndarray,
     return jax.nn.silu(y).astype(xt.dtype), full[:, 1:, :]
 
 
-def ssd_decode_step(cfg, p: Params, x: jnp.ndarray, cache: Dict):
-    """x: (B,1,d) -> (y (B,1,d), new_cache)."""
+def _update_jnp(state, layer, xh, dt, dA, Bm, Cm):
+    """Layer ``layer`` of the stacked state ``state`` (L,B,H,P,N) advanced
+    one token, in jnp: (state with the layer rewritten, y (B,H,P))."""
+    s = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    s = s * dA[..., None, None] + jnp.einsum("bn,bhp,bh->bhpn", Bm, xh, dt)
+    y = jnp.einsum("bn,bhpn->bhp", Cm, s)
+    return jax.lax.dynamic_update_index_in_dim(state, s, layer, 0), y
+
+
+def _update_kernel(state, layer, xh, dt, dA, Bm, Cm, *, interpret=False):
+    """As ``_update_jnp``, by the Pallas kernel: one pass over the layer's
+    state, written back in place."""
+    return ssd_state_update(state, layer, xh * dt[..., None], dA, Bm, Cm,
+                            interpret=interpret)
+
+
+def update_state(state, layer, xh, dt, dA, Bm, Cm):
+    """The kernel where the step is compiled for a TPU, else jnp.  Always
+    jnp under a mesh context, where a ``pallas_call`` is not partitioned
+    over the heads axis, and where the state's last axis does not fill the
+    128 lanes of a TPU tile: XLA then lays the cache's state out heads-minor
+    and would copy the whole stack to the kernel's layout and back every
+    step (hymba's d_state 16, compiled for a described v5e)."""
+    args = (state, layer, xh, dt, dA, Bm, Cm)
+    if get_mesh_context()[0] is not None or state.shape[-1] % 128:
+        return _update_jnp(*args)
+    return jax.lax.platform_dependent(*args, tpu=_update_kernel,
+                                      default=_update_jnp)
+
+
+def ssd_decode_step(cfg, p: Params, x: jnp.ndarray, cache: Dict, layer):
+    """x: (B,1,d) -> (y (B,1,d), new_cache).
+
+    ``cache["state"]`` is the stack's whole (L,B,H,P,N) state; the step
+    rewrites layer ``layer`` of it and returns the whole stack.  The conv
+    tails are this layer's own."""
     H, P, N = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
     xt = x[:, 0, :]
     z = xt @ p["w_z"]
@@ -234,9 +270,8 @@ def ssd_decode_step(cfg, p: Params, x: jnp.ndarray, cache: Dict):
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,H)
     dA = jnp.exp(dt * A)                                      # (B,H)
     xh = xin.reshape(-1, H, P).astype(jnp.float32)
-    state = cache["state"] * dA[..., None, None] + \
-        jnp.einsum("bn,bhp,bh->bhpn", Bm.astype(jnp.float32), xh, dt)
-    y = jnp.einsum("bn,bhpn->bhp", Cm.astype(jnp.float32), state)
+    state, y = update_state(cache["state"], layer, xh, dt, dA,
+                            Bm.astype(jnp.float32), Cm.astype(jnp.float32))
     y = y + xh * p["D"][None, :, None]
     y = y.reshape(-1, H * P).astype(x.dtype)
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)

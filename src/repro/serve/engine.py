@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..kernels.ssd_state import KERNEL as STATE_KERNEL
 from ..models.common import get_mesh_context
 from ..models.lm import STATES
 from ..parallel import sharding as shd
@@ -120,6 +121,19 @@ def cache_bytes(caches) -> Dict[str, int]:
     return out
 
 
+def state_inplace_layers(hlo: str, caches) -> int:
+    """Mamba layers a compiled decode step (its HLO text ``hlo``) updates by
+    the in-place state kernel: the layers of every stacked SSM state in the
+    cache where the step calls the kernel, else 0 (``ssd.update_state``
+    makes one choice for the whole step)."""
+    if not any("tpu_custom_call" in line and STATE_KERNEL in line
+               for line in hlo.splitlines()):
+        return 0
+    return sum(leaf.shape[0]
+               for path, leaf in jax.tree_util.tree_leaves_with_path(caches)
+               if getattr(path[-1], "key", None) == "state")
+
+
 class ServeEngine:
     """Single-host engine over a ModelBundle (works meshed or unmeshed).
 
@@ -150,7 +164,11 @@ class ServeEngine:
         # reuse its donated input (the whole cache when the donation takes),
         # set by ``compile`` (None where the backend does not report it);
         # cache_bytes: the decode cache's bytes, {"kv": K/V leaves,
-        # "state": the leaves a step rewrites whole}, set by ``compile``
+        # "state": the leaves a step rewrites whole}, set by ``compile``;
+        # state_inplace_layers: the Mamba layers whose state the compiled
+        # decode step updates in place by the Pallas kernel, in one pass
+        # over HBM (0 off the TPU, under a mesh, or with no SSM state), set
+        # by ``compile``
         self.stats: Dict[str, float] = {"prefills": 0, "decode_steps": 0,
                                         "tokens_out": 0, "admitted": 0,
                                         "compiles": 0, "prefill_s": 0.0,
@@ -209,6 +227,8 @@ class ServeEngine:
         mem = decode.memory_analysis()
         self.stats["decode_aliased_bytes"] = getattr(
             mem, "alias_size_in_bytes", None)
+        self.stats["state_inplace_layers"] = state_inplace_layers(
+            decode.as_text(), caches)
         self.stats["compiles"] += _thread_compiles() - compiles0
         return out
 

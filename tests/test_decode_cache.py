@@ -13,7 +13,7 @@ import pytest
 from repro.configs import get_config
 from repro.configs.base import reduce_for_smoke
 from repro.launch.mesh import make_test_mesh
-from repro.models import build, lm
+from repro.models import build, lm, ssd
 from repro.models.common import set_activation_rules, set_mesh_context
 from repro.parallel import sharding as shd
 from repro.serve import EngineConfig, ServeEngine, seed_decode_cache
@@ -44,11 +44,22 @@ def select_write_layer(cache, layer, new):
     return out
 
 
+def select_update_state(state, layer, xh, dt, dA, Bm, Cm):
+    """A layer's SSM state advanced in jnp and written into its stack by a
+    one-hot select."""
+    s = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    s = s * dA[..., None, None] + jnp.einsum("bn,bhp,bh->bhpn", Bm, xh, dt)
+    y = jnp.einsum("bn,bhpn->bhp", Cm, s)
+    return select_write_layer({"state": state}, layer, {"state": s})[
+        "state"], y
+
+
 @contextlib.contextmanager
 def select_writes(monkeypatch):
     with monkeypatch.context() as m:
         m.setattr(lm, "update_cache", select_update_cache)
         m.setattr(lm, "write_layer", select_write_layer)
+        m.setattr(ssd, "_update_jnp", select_update_state)
         yield
 
 
@@ -107,7 +118,10 @@ def decode_logits(eng, prompts, tokens, donate):
     batch, _ = eng._pad_batch([Request(-1, p) for p in prompts])
     _, caches = jax.jit(bundle.prefill)(params, batch)
     caches = seed_decode_cache(bundle, caches, BATCH, MAX_SEQ)
-    step = jax.jit(bundle.decode, donate_argnums=(1,) if donate else ())
+    # a new function each call: jit would reuse bundle.decode's trace, made
+    # before or after the writes were patched
+    step = jax.jit(lambda *args: bundle.decode(*args),
+                   donate_argnums=(1,) if donate else ())
     out = []
     for i in range(NEW - 1):
         logits, caches = step(params, caches, jnp.asarray(tokens[:, i:i + 1]),

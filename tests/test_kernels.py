@@ -1,6 +1,9 @@
 """Per-kernel validation: Pallas in interpret mode (on the CPU) vs pure-jnp
 oracle, sweeping shapes/dtypes + hypothesis property sweeps.  Compiles for
 the TPU itself are in test_tpu_compile.py."""
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +14,12 @@ try:
 except ModuleNotFoundError:  # property tests skip, unit tests still run
     from _hypothesis_fallback import given, settings, st
 
+from repro.configs import get_config
+from repro.configs.base import reduce_for_smoke
 from repro.kernels import ops, ref
+from repro.models import build, ssd
+from repro.models.common import set_mesh_context
+from repro.serve import seed_decode_cache
 
 
 def _allclose(a, b, dtype):
@@ -133,19 +141,24 @@ def test_decode_attention(S, length, dtype):
     _allclose(out, ref.decode_attention_ref(q, kc, vc, length), dtype)
 
 
+def _count_compiles(fun_name):
+    """(list the compiles of ``fun_name`` are appended to, listener)."""
+    compiles = []
+
+    def count(event, duration_s, **kwargs):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and kwargs.get("fun_name") == fun_name):
+            compiles.append(duration_s)
+    return compiles, count
+
+
 def test_decode_attention_length_is_traced():
     """Every decode position reuses one compiled kernel."""
     B, S, H, hd = 1, 256, 2, 64
     q = jax.random.normal(jax.random.PRNGKey(0), (B, H, hd))
     kc = jax.random.normal(jax.random.PRNGKey(1), (B, S, H, hd))
     vc = jax.random.normal(jax.random.PRNGKey(2), (B, S, H, hd))
-    compiles = []
-
-    def count(event, duration_s, **kwargs):
-        if (event == "/jax/core/compile/backend_compile_duration"
-                and kwargs.get("fun_name") == "jit(decode_attention)"):
-            compiles.append(duration_s)
-
+    compiles, count = _count_compiles("jit(decode_attention)")
     ops.decode_attention.clear_cache()
     jax.monitoring.register_event_duration_secs_listener(count)
     try:
@@ -157,3 +170,122 @@ def test_decode_attention_length_is_traced():
     finally:
         jax.monitoring.unregister_event_duration_listener(count)
     assert len(compiles) == 1
+
+
+# ---------------------------------------------------------------------------
+# SSD decode-step state update, in place in a stacked cache
+# ---------------------------------------------------------------------------
+
+def _close_f32(got, want):
+    """Equal to float32 rounding: a sum over the state's last axis rounds
+    to its terms' scale, not its own."""
+    want = np.asarray(want)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                               atol=1e-5 * float(np.abs(want).max()))
+
+
+# (layers, batch, heads, headdim, state): granite_4_0_h_micro's and
+# mamba2_1_3b's widths, hymba_1_5b's, the reduced smoke configs'
+@pytest.mark.parametrize("shape", [(3, 2, 64, 64, 128), (2, 4, 64, 64, 128),
+                                   (3, 2, 64, 50, 16), (2, 4, 16, 8, 8)],
+                         ids=["granite_b2", "granite_b4", "hymba", "smoke"])
+def test_ssd_state_update_matches_the_jnp_update(shape):
+    """Each layer's new state and ``y`` agree with the model's jnp update,
+    every other layer of the slab is left bit for bit, and the layer index
+    is traced: one compile serves every layer."""
+    L, B, H, P, N = shape
+    ks = jax.random.split(jax.random.PRNGKey(L * B + P), 6)
+    state = jax.random.normal(ks[0], shape)
+    xh = jax.random.normal(ks[1], (B, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (B, H)))
+    dA = jnp.exp(-dt * jax.random.uniform(ks[3], (H,), minval=0.5,
+                                          maxval=2.0))
+    Bm = jax.random.normal(ks[4], (B, N))
+    Cm = jax.random.normal(ks[5], (B, N))
+    compiles, count = _count_compiles("jit(ssd_state_update)")
+    ops.ssd_state_update.clear_cache()
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        for layer in range(L):
+            got, y = ops.ssd_state_update(state, jnp.int32(layer),
+                                          xh * dt[..., None], dA, Bm, Cm,
+                                          interpret=True)
+            want, y_want = ssd._update_jnp(state, layer, xh, dt, dA, Bm, Cm)
+            _close_f32(got[layer], want[layer])
+            _close_f32(y, y_want)
+            others = np.arange(L) != layer
+            np.testing.assert_array_equal(np.asarray(got)[others],
+                                          np.asarray(state)[others])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    assert got.dtype == y.dtype == jnp.float32
+    assert len(compiles) == 1
+
+
+def _granite_smoke(**change):
+    return dataclasses.replace(
+        reduce_for_smoke(get_config("granite_4_0_h_micro")),
+        param_dtype="float32", compute_dtype="float32", **change)
+
+
+@pytest.mark.parametrize("make_cfg", [
+    _granite_smoke,
+    # one scanned stack of (mamba, mamba, attention) x 2: 2-layer slabs
+    functools.partial(_granite_smoke, n_layers=6,
+                      layer_kinds=("mamba", "mamba", "dense") * 2)],
+    ids=["smoke", "smoke_scanned"])
+def test_decode_steps_with_the_state_kernel_match_the_jnp_path(
+        make_cfg, monkeypatch):
+    """Decode steps of a small granite, the state updated by the kernel (in
+    interpret mode, patched in where the CPU takes jnp), give the jnp
+    path's logits and caches."""
+    cfg = make_cfg()
+    bundle = build(cfg)
+    params = bundle.init(jax.random.PRNGKey(0))
+    batch, prompt, steps, max_seq = 2, 8, 4, 16
+    rng = np.random.default_rng(3)
+    tokens = rng.integers(0, cfg.vocab_size, (batch, prompt + steps))
+    _, caches = jax.jit(bundle.prefill)(
+        params, {"tokens": jnp.asarray(tokens[:, :prompt], jnp.int32)})
+    caches = seed_decode_cache(bundle, caches, batch, max_seq)
+
+    def decode():
+        # a new function each time: jit would reuse bundle.decode's trace
+        step = jax.jit(lambda *args: bundle.decode(*args))
+        out, c = [], caches
+        for i in range(steps):
+            logits, c = step(params, c, jnp.asarray(
+                tokens[:, prompt + i:prompt + i + 1], jnp.int32),
+                jnp.int32(prompt + i))
+            out.append(np.asarray(logits))
+        return np.stack(out), c
+
+    want, want_caches = decode()
+    monkeypatch.setattr(ssd, "_update_jnp", functools.partial(
+        ssd._update_kernel, interpret=True))
+    got, got_caches = decode()
+    _close_f32(got, want)
+    assert jax.tree.structure(got_caches) == jax.tree.structure(want_caches)
+    for g, w in zip(jax.tree.leaves(got_caches), jax.tree.leaves(want_caches)):
+        assert g.dtype == w.dtype
+        _close_f32(g, w)
+
+
+@pytest.mark.parametrize("state_dim,meshed,kernel", [
+    (128, False, True), (128, True, False), (16, False, False)],
+    ids=["lane_dense", "meshed", "hymba_d_state_16"])
+def test_state_update_stages_the_kernel_where_it_updates_in_place(
+        state_dim, meshed, kernel):
+    """The kernel is staged for a TPU, but not under a mesh context (a
+    ``pallas_call`` is not partitioned over the heads axis), nor for a state
+    whose last axis is narrower than a tile's 128 lanes."""
+    B, H, P, N = 2, 16, 8, state_dim
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    args = [f32((2, B, H, P, N)), jax.ShapeDtypeStruct((), jnp.int32),
+            f32((B, H, P)), f32((B, H)), f32((B, H)), f32((B, N)),
+            f32((B, N))]
+    if meshed:   # conftest clears it after the test
+        set_mesh_context(jax.make_mesh((1,), ("model",)))
+    # a new function: make_jaxpr would reuse an earlier trace
+    jaxpr = str(jax.make_jaxpr(lambda *a: ssd.update_state(*a))(*args))
+    assert ("pallas_call" in jaxpr) == kernel

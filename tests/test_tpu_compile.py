@@ -3,8 +3,9 @@
 The TPU compiler refuses what interpret mode accepts: blocks off the
 (8, 128) tiling, rank-1 blocks, more VMEM than a kernel may use, programs
 larger than the chip's memory.  These tests compile the Pallas kernels at
-qwen2_0_5b / mamba2_1_3b widths and the served qwen2_0_5b prefill and
-decode steps at full width, for one chip of a described ``v5e:2x2`` host.
+qwen2_0_5b / mamba2_1_3b / granite_4_0_h_micro widths and the served
+qwen2_0_5b and granite_4_0_h_micro prefill and decode steps at full width,
+for one chip of a described ``v5e:2x2`` host.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -12,6 +13,7 @@ file.  The persistent compile cache is off around these compiles (an entry
 written for a described chip cannot be read back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +49,7 @@ def _kernel_case(name, spec):
     from repro.kernels.decode_attention import decode_attention
     from repro.kernels.flash_attention import flash_attention
     from repro.kernels.ssd_scan import ssd_scan
+    from repro.kernels.ssd_state import ssd_state_update
     from repro.kernels.streamed_matmul import streamed_matmul
 
     bf16, f32 = jnp.bfloat16, jnp.float32
@@ -62,12 +65,24 @@ def _kernel_case(name, spec):
                           spec((1, 2048, 64), f32), spec((64,), f32),
                           spec((1, 2048, 128), bf16),
                           spec((1, 2048, 128), bf16)]
+    if name == "ssd_state_update":    # granite_4_0_h_micro decode, batch 32
+        return ssd_state_update, [spec((4, 32, 64, 64, 128), f32),
+                                  spec((), jnp.int32),
+                                  spec((32, 64, 64), f32), spec((32, 64), f32),
+                                  spec((32, 128), f32), spec((32, 128), f32)]
+    if name == "ssd_state_update_hymba":  # hymba_1_5b: P 50, N 16, batch 8
+        return ssd_state_update, [spec((32, 8, 64, 50, 16), f32),
+                                  spec((), jnp.int32),
+                                  spec((8, 64, 50), f32), spec((8, 64), f32),
+                                  spec((8, 16), f32), spec((8, 16), f32)]
     # qwen2_0_5b MLP up-projection: K = d_model = 896 = 7 x 128
     return streamed_matmul, [spec((4096, 896), bf16), spec((896, 4864), bf16)]
 
 
 @pytest.mark.parametrize("name", ["flash_attention", "decode_attention",
-                                  "ssd_scan", "streamed_matmul"])
+                                  "ssd_scan", "ssd_state_update",
+                                  "ssd_state_update_hymba",
+                                  "streamed_matmul"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_case(
         name, lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
@@ -133,19 +148,43 @@ def test_granite_serving_steps_compile_for_v5e(one_chip):
     shape (batch 32, 1024-token prompts, max_seq 1536): prefill and decode
     fit one chip, and the decode step hands back its whole donated cache,
     the K/V of its 4 attention layers and the state and conv tails of its
-    36 Mamba layers, in place."""
+    36 Mamba layers, in place.  Each Mamba layer's state is updated by the
+    kernel where it lies: no instruction outside it outputs a whole layer's
+    state, and the step's scratch is short of one layer's state, which the
+    update fusion held before the kernel (552,727,552 bytes of temp)."""
+    from repro.kernels.ssd_state import KERNEL
     from repro.models import build
     from repro.serve import EngineConfig, ServeEngine
 
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
     bundle = build(get_config("granite_4_0_h_micro"))
-    params = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
-        jax.eval_shape(bundle.init, jax.random.PRNGKey(0)))
-    engine = ServeEngine(bundle, params,
-                         EngineConfig(batch_size=32, max_seq=1536))
+    engine = ServeEngine(bundle, on_chip(jax.eval_shape(
+        bundle.init, jax.random.PRNGKey(0))),
+        EngineConfig(batch_size=32, max_seq=1536))
     engine.compile(prompt_len=1024)
     # K/V: 4 x 2 x 32 x 1536 x 512 x 2 bytes; state: 36 x 32 x (64 x 64 x
     # 128 x 4 + 3 x 4352 x 2) bytes
     assert engine.stats["cache_bytes"] == {"kv": 402_653_184,
                                            "state": 2_446_000_128}
     assert engine.stats["decode_aliased_bytes"] == 2_848_653_312
+    assert engine.stats["state_inplace_layers"] == 36
+
+    caches = jax.eval_shape(lambda: bundle.init_cache(32, 1536))
+    step = engine._decode.lower(
+        engine.params, on_chip(caches),
+        on_chip(jax.ShapeDtypeStruct((32, 1), jnp.int32)),
+        jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    layer_state = 32 * 64 * 64 * 128 * 4
+    assert step.memory_analysis().temp_size_in_bytes <= \
+        552_727_552 - layer_state
+    # each instruction's output type, between its name and its opcode
+    outputs = [(m.group(1), line) for line in step.as_text().splitlines()
+               if (m := re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ",
+                                 line))]
+    assert sum(KERNEL in line and "tpu_custom_call" in line
+               for _, line in outputs) == 9   # per step of the layer scan
+    assert not [line for out, line in outputs
+                if "f32[32,64,64,128]" in out and KERNEL not in line]
