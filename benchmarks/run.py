@@ -1,12 +1,10 @@
-"""Benchmark harness: one function per paper table/figure + kernel
-micro-bench + roofline report.  Prints ``name,us_per_call,derived`` CSV rows
-plus per-figure data tables and paper-claim comparisons.
+"""Paper figures: runs each table/figure of ``paper_figures`` and prints
+``name,us_per_call,derived`` CSV rows (the figure's own host wall time and
+its claims), then per-figure data tables and paper-claim comparisons.
 """
 from __future__ import annotations
 
 import json
-import os
-import sys
 import time
 
 
@@ -37,75 +35,8 @@ def run_paper_figures() -> None:
         print()
 
 
-def run_kernel_bench() -> None:
-    """Wall-time microbench of the jnp oracles (CPU) — the Pallas kernels
-    target TPU and are validated in interpret mode by the tests."""
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels import ref
-
-    print("== kernels (CPU oracle timings) ==")
-    print("name,us_per_call,derived")
-    k = jax.random.PRNGKey(0)
-    x = jax.random.normal(k, (512, 512), jnp.float32)
-    w = jax.random.normal(k, (512, 512), jnp.float32)
-    f = jax.jit(ref.matmul_ref)
-    us, _ = _time_us(lambda: jax.block_until_ready(f(x, w)))
-    print(f"matmul_ref_512,{us:.0f},{{\"gflops\": "
-          f"{2 * 512**3 / (us / 1e6) / 1e9:.1f}}}")
-    q = jax.random.normal(k, (1, 4, 512, 64), jnp.float32)
-    fa = jax.jit(lambda q: ref.flash_attention_ref(q, q, q))
-    us, _ = _time_us(lambda: jax.block_until_ready(fa(q)))
-    print(f"attention_ref_512,{us:.0f},{{}}")
-    print()
-
-
-def run_roofline_report() -> None:
-    """Aggregate the dry-run JSON results into the §Roofline table."""
-    results_dir = os.environ.get("DRYRUN_RESULTS",
-                                 "/root/repo/results/dryrun")
-    if not os.path.isdir(results_dir):
-        print("== roofline: no dry-run results yet ==")
-        return
-    rows = []
-    for fn in sorted(os.listdir(results_dir)):
-        if not fn.endswith(".json"):
-            continue
-        with open(os.path.join(results_dir, fn)) as f:
-            cell = json.load(f)
-        if cell.get("status") == "skip":
-            rows.append({"arch": cell["arch"], "shape": cell["shape"],
-                         "mesh": cell["mesh"], "status": "SKIP",
-                         "note": cell["reason"]})
-            continue
-        r = cell.get("roofline")
-        if not r:
-            continue
-        rows.append({
-            "arch": r["arch"], "shape": r["shape"], "mesh": r["mesh"],
-            "status": "ok",
-            "t_compute_ms": round(r["t_compute"] * 1e3, 3),
-            "t_memory_ms": round(r["t_memory"] * 1e3, 3),
-            "t_collective_ms": round(r["t_collective"] * 1e3, 3),
-            "bottleneck": r["bottleneck"],
-            "useful_flops_ratio": round(r["useful_flops_ratio"], 3),
-            "mfu": round(r["mfu"], 4),
-        })
-    print("== roofline (from dry-run) ==")
-    if rows:
-        keys = ["arch", "shape", "mesh", "status", "t_compute_ms",
-                "t_memory_ms", "t_collective_ms", "bottleneck",
-                "useful_flops_ratio", "mfu", "note"]
-        print(",".join(keys))
-        for r in rows:
-            print(",".join(str(r.get(k, "")) for k in keys))
-    print()
-
-
 def main() -> None:
     run_paper_figures()
-    run_kernel_bench()
-    run_roofline_report()
 
 
 if __name__ == "__main__":

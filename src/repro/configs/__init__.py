@@ -1,2 +1,1 @@
 from .base import ModelConfig, ARCH_IDS, ALIASES, get_config, registry
-from .shapes import SHAPES, SHAPE_ORDER, ShapeSpec, applicable, all_cells, is_subquadratic

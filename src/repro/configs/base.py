@@ -82,9 +82,6 @@ class ModelConfig:
     frontend_seq: int = 0           # patch/frame embeddings per sample
     frontend_dim: int = 0           # embedding width delivered by the stub
 
-    # --- parallelism ---
-    attn_shard: str = "auto"   # auto | heads | seq | replicated
-
     # --- numerics ---
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
@@ -164,20 +161,6 @@ class ModelConfig:
         rest = self.n_layers - self.first_k_dense
         n_moe = rest // self.moe_interleave
         return (n_moe, self.n_layers - n_moe)
-
-    def active_param_count(self) -> int:
-        """Per-token active params (MoE: shared + top_k routed)."""
-        if self.family != "moe":
-            return self.param_count()
-        d = self.d_model
-        hd, H, KV = self.head_dim_, self.n_heads, self.n_kv_heads
-        attn = d * H * hd + 2 * d * KV * hd + H * hd * d
-        n = 2 * self.padded_vocab * d
-        active_ff = (self.n_shared_experts + self.top_k) * 3 * d * self.moe_d_ff
-        n_moe, n_dense = self.moe_layer_split()
-        n += n_moe * (attn + active_ff + d * self.n_experts)
-        n += n_dense * (attn + 3 * d * self.d_ff)
-        return n
 
 
 def reduce_for_smoke(cfg: "ModelConfig") -> "ModelConfig":

@@ -3,7 +3,7 @@
 32L, d_model=1600, 25 heads (GQA kv=5), d_ff=5504, vocab=32001, ssm_state=16.
 Sliding-window attention (most layers in the paper use SWA-1024; we use SWA
 everywhere — meta-tokens and the 3 global-attention layers are omitted, see
-DESIGN.md §Arch-applicability) keeps it sub-quadratic, so long_500k runs.
+DESIGN.md §Arch-applicability) keeps it sub-quadratic.
 """
 from .base import ModelConfig
 

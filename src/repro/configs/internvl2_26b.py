@@ -1,8 +1,8 @@
 """InternVL2-26B — InternViT + InternLM2 backbone [arXiv:2404.16821; hf].
 
 48L, d_model=6144, 48 heads (GQA kv=8), d_ff=16384, vocab=92553.  The ViT
-frontend is a STUB: input_specs() provides precomputed, projected patch
-embeddings (B, 256, 6144) that are concatenated ahead of the text tokens.
+frontend is a STUB: the batch's ``patch_embeds`` are precomputed, projected
+patch embeddings (B, 256, 6144), concatenated ahead of the text tokens.
 """
 from .base import ModelConfig
 

@@ -1,8 +1,8 @@
 """Mamba2-1.3B — SSD (state-space duality), attention-free [arXiv:2405.21060].
 
 48L, d_model=2048, d_ff=0, vocab=50280, ssm_state=128.
-d_inner = 2*2048 = 4096, headdim 64 -> 64 SSD heads.  Sub-quadratic: runs
-long_500k; decode state is constant-size (no KV growth).
+d_inner = 2*2048 = 4096, headdim 64 -> 64 SSD heads.  Sub-quadratic: its
+decode state is constant-size (no KV growth).
 """
 from .base import ModelConfig
 

@@ -1,8 +1,8 @@
 """Whisper-large-v3 — encoder-decoder audio backbone [arXiv:2212.04356].
 
 32 decoder layers (and 32 encoder layers), d_model=1280, 20 heads (MHA,
-kv=20), d_ff=5120, vocab=51866.  The conv frontend is a STUB: input_specs()
-provides precomputed frame embeddings (B, 1500, 1280).  LayerNorm + GELU.
+kv=20), d_ff=5120, vocab=51866.  The conv frontend is a STUB: the batch's
+``frames`` are precomputed frame embeddings (B, 1500, 1280).  LayerNorm + GELU.
 """
 from .base import ModelConfig
 

@@ -1,8 +1,8 @@
 """Uniform model API: one bundle per architecture config.
 
 ``build(cfg)`` returns a :class:`ModelBundle` whose members are plain
-functions — the launcher, trainer, server, dry-run and tests all consume
-this one interface:
+functions — the launcher, trainer, server and tests all consume this one
+interface:
 
     init(key)                      -> params
     loss(params, batch)            -> (scalar, metrics)
@@ -11,24 +11,16 @@ this one interface:
     decode(params, caches, token, pos) -> (logits, caches)
     init_cache(batch, max_seq)     -> caches
     param_logical_axes()           -> pytree of logical-axis tuples
-    input_specs(shape, kind)       -> ShapeDtypeStruct batch for .lower()
-
-``input_specs`` is the multi-pod dry-run's entry point: weak-type-correct,
-shardable stand-ins, no device allocation.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Callable
 
 import jax
-import jax.numpy as jnp
 
 from . import lm, whisper
-from .common import dtype_of
 from ..configs.base import ModelConfig
-from ..configs.shapes import ShapeSpec
 
 
 @dataclasses.dataclass
@@ -41,31 +33,6 @@ class ModelBundle:
     decode: Callable
     init_cache: Callable
     param_logical_axes: Callable
-    input_specs: Callable
-
-
-def _text_len(cfg: ModelConfig, seq_len: int) -> int:
-    """VLM frontends consume part of the sequence budget."""
-    if cfg.family == "vlm":
-        return seq_len - cfg.frontend_seq
-    return seq_len
-
-
-def _batch_specs(cfg: ModelConfig, shape: ShapeSpec) -> Dict[str, Any]:
-    B, S = shape.global_batch, shape.seq_len
-    dt = dtype_of(cfg.param_dtype)
-    if cfg.family == "encdec":
-        return {
-            "frames": jax.ShapeDtypeStruct((B, cfg.enc_seq, cfg.frontend_dim), dt),
-            "tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
-        }
-    if cfg.family == "vlm":
-        return {
-            "tokens": jax.ShapeDtypeStruct((B, _text_len(cfg, S)), jnp.int32),
-            "patch_embeds": jax.ShapeDtypeStruct(
-                (B, cfg.frontend_seq, cfg.frontend_dim), dt),
-        }
-    return {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32)}
 
 
 def build(cfg: ModelConfig) -> ModelBundle:
@@ -102,20 +69,6 @@ def build(cfg: ModelConfig) -> ModelBundle:
     def init_cache(batch, max_seq):
         return mod.init_cache(cfg, batch, max_seq)
 
-    def input_specs(shape: ShapeSpec, kind: Optional[str] = None):
-        kind = kind or shape.kind
-        if kind in ("train", "prefill"):
-            return _batch_specs(cfg, shape)
-        # decode: one new token against a seq_len-deep cache
-        B = shape.global_batch
-        cache_specs = jax.eval_shape(lambda: init_cache(B, shape.seq_len))
-        return {
-            "token": jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            "pos": jax.ShapeDtypeStruct((), jnp.int32),
-            "caches": cache_specs,
-        }
-
     return ModelBundle(cfg=cfg, init=init, loss=loss, forward=forward,
                        prefill=prefill, decode=decode, init_cache=init_cache,
-                       param_logical_axes=param_logical_axes,
-                       input_specs=input_specs)
+                       param_logical_axes=param_logical_axes)

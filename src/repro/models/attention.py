@@ -1,7 +1,7 @@
 """Attention: chunked flash-style forward, GQA, sliding-window, cross,
 and split-KV decode — pure JAX (the Pallas kernels in ``repro/kernels`` are
-the TPU-optimized versions of the same math; the model uses these jnp paths
-on the CPU dry-run backend).
+versions of the same math, off the model path; the model uses these jnp
+paths).
 
 Sharding strategy (see DESIGN.md):
   * projections — TP over the *fused* head dim (H*hd).  Head counts of the
@@ -14,20 +14,18 @@ Sharding strategy (see DESIGN.md):
   * decode — split-KV: the cache's sequence dim is sharded over ``model``;
     partial softmax statistics combine exactly through jnp reductions, which
     GSPMD lowers to the matching collectives.  Per-device cache bytes drop
-    by the model-axis size — this IS the roofline story for decode shapes.
+    by the model-axis size.
 """
 from __future__ import annotations
 
-import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .common import (Params, apply_rope, dense_init, get_mesh_context,
-                     get_scan_unroll, rmsnorm)
+from .common import Params, apply_rope, dense_init, get_mesh_context, rmsnorm
 
 NEG_INF = -1e30
 
@@ -166,13 +164,11 @@ def chunked_attention(cfg, q, k, v, q_positions, kv_positions, *,
                 jnp.zeros((B, KV, G, cq), jnp.float32),
                 jnp.zeros((B, KV, G, cq, hd), jnp.float32))
         (m, l, acc), _ = jax.lax.scan(
-            jax.checkpoint(kv_step), init, jnp.arange(n_k),
-            unroll=True if get_scan_unroll() else 1)
+            jax.checkpoint(kv_step), init, jnp.arange(n_k))
         return acc / jnp.maximum(l, 1e-30)[..., None]      # (B,KV,G,cq,hd)
 
     _, outs = jax.lax.scan(lambda c, qi: (c, per_q_block(qi)), 0,
-                           jnp.arange(n_q),
-                           unroll=True if get_scan_unroll() else 1)
+                           jnp.arange(n_q))
     out = jnp.moveaxis(outs, 0, 3)                          # (B,KV,G,n_q,cq,hd)
     out = out.reshape(B, KV, G, Sq, hd).transpose(0, 3, 1, 2, 4)
     return out.reshape(B, Sq, H, hd).astype(q.dtype)
@@ -211,23 +207,20 @@ def _banded_attention(qg, k, v, q_positions, kv_positions, *,
 
     _, outs = jax.lax.scan(
         lambda c, qi: (c, jax.checkpoint(per_q_block)(qi)), 0,
-        jnp.arange(n_q), unroll=True if get_scan_unroll() else 1)
+        jnp.arange(n_q))
     out = jnp.moveaxis(outs, 0, 3).reshape(B, KV, G, Sq, hd)
     return out.transpose(0, 3, 1, 2, 4)
 
 
 def _flash_full(cfg, q, k, v, *, causal, window, use_rope, base_pos: int = 0):
-    """Rope + chunked attention, sharded per ``cfg.attn_shard``:
+    """Rope + chunked attention.  Under a mesh whose model axis has M > 1
+    devices, the core is sharded by what the shapes allow, first match:
 
-      heads      — H and KV divide the model axis: each shard attends its
-                   own heads over the full sequence.  Zero collectives (the
-                   §Perf winner where legal — e.g. deepseek 16/16 heads).
-      seq        — query-sequence shards + KV all-gather (legal for any head
-                   count; the default for the assigned archs).
-      replicated — no sharding of the attention core (model-axis devices
-                   repeat it).  Only sensible when attention is a small
-                   fraction of the step and the gathers dominate.
-      auto       — heads if divisible, else seq if S divides, else replicated.
+      seq        — M divides S: query-sequence shards + KV all-gather
+                   (legal for any head count).
+      heads      — M divides H and KV: each shard attends its own heads
+                   over the full sequence, with no collective.
+      replicated — neither: the model axis's devices repeat the core.
 
     q/k/v are un-roped projections, (B,S,*,hd).  Returns (y, k_roped, v).
     """
@@ -252,45 +245,35 @@ def _flash_full(cfg, q, k, v, *, causal, window, use_rope, base_pos: int = 0):
                               causal=causal, window=window)
         return y, k_r, v_l
 
-    if mesh is not None and model_axis in mesh.axis_names:
-        M = mesh.shape[model_axis]
-        mode = cfg.attn_shard
-        if mode == "auto":
-            # baseline (paper-faithful) default: sequence sharding; "heads"
-            # is the explicit §Perf opt-in where head counts divide the mesh
-            if M > 1 and S % M == 0:
-                mode = "seq"
-            elif M > 1 and cfg.n_heads % M == 0 and cfg.n_kv_heads % M == 0:
-                mode = "heads"
+    M = mesh.shape[model_axis] if mesh is not None and \
+        model_axis in mesh.axis_names else 1
+    if M > 1 and S % M == 0:
+        dp = P(data_spec, model_axis, None, None)
+
+        def body(q_l, k_l, v_l):
+            i = jax.lax.axis_index(model_axis)
+            return local(q_l, k_l, v_l, i, M)
+
+        return jax.shard_map(body, mesh=mesh, in_specs=(dp, dp, dp),
+                             out_specs=(dp, dp, dp),
+                             check_vma=False)(q, k, v)
+    if M > 1 and cfg.n_heads % M == 0 and cfg.n_kv_heads % M == 0:
+        dq = P(data_spec, None, model_axis, None)
+
+        def body_h(q_l, k_l, v_l):
+            qpos = base_pos + jnp.arange(S)
+            if use_rope:
+                q_r = apply_rope(q_l, qpos, cfg.rope_theta)
+                k_r = apply_rope(k_l, qpos, cfg.rope_theta)
             else:
-                mode = "replicated"
-        if mode == "heads" and M > 1 and cfg.n_heads % M == 0 and                 cfg.n_kv_heads % M == 0:
-            dq = P(data_spec, None, model_axis, None)
+                q_r, k_r = q_l, k_l
+            y = chunked_attention(cfg, q_r, k_r, v_l, qpos, qpos,
+                                  causal=causal, window=window)
+            return y, k_r, v_l
 
-            def body_h(q_l, k_l, v_l):
-                qpos = base_pos + jnp.arange(S)
-                if use_rope:
-                    q_r = apply_rope(q_l, qpos, cfg.rope_theta)
-                    k_r = apply_rope(k_l, qpos, cfg.rope_theta)
-                else:
-                    q_r, k_r = q_l, k_l
-                y = chunked_attention(cfg, q_r, k_r, v_l, qpos, qpos,
-                                      causal=causal, window=window)
-                return y, k_r, v_l
-
-            return jax.shard_map(body_h, mesh=mesh, in_specs=(dq, dq, dq),
-                                 out_specs=(dq, dq, dq), check_vma=False
-                                 )(q, k, v)
-        if mode == "seq" and M > 1 and S % M == 0:
-            dp = P(data_spec, model_axis, None, None)
-
-            def body(q_l, k_l, v_l):
-                i = jax.lax.axis_index(model_axis)
-                return local(q_l, k_l, v_l, i, M)
-
-            return jax.shard_map(body, mesh=mesh, in_specs=(dp, dp, dp),
-                                 out_specs=(dp, dp, dp),
-                                 check_vma=False)(q, k, v)
+        return jax.shard_map(body_h, mesh=mesh, in_specs=(dq, dq, dq),
+                             out_specs=(dq, dq, dq), check_vma=False
+                             )(q, k, v)
     return local(q, k, v, 0, 1)
 
 

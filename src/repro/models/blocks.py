@@ -103,7 +103,6 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
                   cache: Optional[Dict] = None,
                   cache_pos: Optional[jnp.ndarray] = None,
                   enc_out: Optional[jnp.ndarray] = None,
-                  window_override: Optional[int] = None,
                   layer: Optional[jnp.ndarray] = None
                   ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
     """Returns (y, new_cache, aux_loss).  ``cache`` is this layer's slice,
@@ -119,7 +118,6 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
     """
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
-    window = cfg.sliding_window if window_override is None else window_override
     decoding = cache is not None and x.shape[1] == 1
 
     if kind in ("ssm", "mamba"):
@@ -140,12 +138,12 @@ def block_forward(cfg, p: Params, x: jnp.ndarray, kind: str, *,
     if decoding:
         attn_cache = {"k": cache["k"], "v": cache["v"]}
         y_attn, kv = attention_forward(
-            cfg, p["attn"], h, causal=causal, window=window,
+            cfg, p["attn"], h, causal=causal, window=cfg.sliding_window,
             use_rope=cfg.rope, cache=attn_cache, cache_pos=cache_pos)
         new_cache.update(kv)
     else:
         y_attn, kv = attention_forward(
-            cfg, p["attn"], h, causal=causal, window=window,
+            cfg, p["attn"], h, causal=causal, window=cfg.sliding_window,
             use_rope=cfg.rope)
         if kv is not None:
             new_cache["k"], new_cache["v"] = map(fuse_heads, kv)

@@ -113,10 +113,9 @@ def set_activation_rules(rules: Dict[str, Optional[Any]]) -> None:
 def set_mesh_context(mesh, data_spec=("data",), model_axis="model",
                      moe_ff_axis=None) -> None:
     """Install the mesh used by shard_map-based modules (attention, MoE).
-    ``data_spec`` is the tuple of mesh axes that shard the batch dim
-    (("pod","data") on the multi-pod mesh).  ``moe_ff_axis`` shards the
-    expert hidden dim (TP/EP recipe: expert weights 2D-sharded, no
-    gathers)."""
+    ``data_spec`` is the tuple of mesh axes that shard the batch dim.
+    ``moe_ff_axis`` shards the expert hidden dim (TP/EP recipe: expert
+    weights 2D-sharded, no gathers)."""
     _MESH_CTX["mesh"] = mesh
     _MESH_CTX["data_spec"] = tuple(data_spec)
     _MESH_CTX["model_axis"] = model_axis
@@ -134,20 +133,6 @@ def get_moe_ff_axis():
 def clear_mesh_context() -> None:
     _MESH_CTX["mesh"] = None
     set_activation_rules({})
-
-
-_SCAN_UNROLL = {"on": False}
-
-
-def set_scan_unroll(on: bool) -> None:
-    """Dry-run roofline mode: fully unroll layer scans so XLA cost analysis
-    sees every layer (while-loop bodies are otherwise counted once).  Used
-    only for the small-L calibration lowers in launch/dryrun.py."""
-    _SCAN_UNROLL["on"] = bool(on)
-
-
-def get_scan_unroll() -> bool:
-    return _SCAN_UNROLL["on"]
 
 
 def with_logical_constraint(x: jnp.ndarray, *logical_axes: Optional[str]):

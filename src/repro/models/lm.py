@@ -9,17 +9,15 @@ bounded by one layer.  MoE interleaving (llama4) scans over (dense, moe)
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .attention import update_cache
 from .blocks import block_forward, block_init, init_block_cache
-from .common import (Params, apply_norm, dtype_of, embed_init,
-                     get_scan_unroll, norm_init, softmax_cross_entropy,
-                     with_logical_constraint)
+from .common import (Params, apply_norm, dtype_of, embed_init, norm_init,
+                     softmax_cross_entropy, with_logical_constraint)
 
 # decode-cache leaves that a decode step rewrites whole, layer by layer: the
 # SSM state, which a layer's step takes for the whole stack and hands back
@@ -159,7 +157,6 @@ def _scan_stack(cfg, stack_params, x, kinds: Tuple[str, ...], *,
     described v5e).
     """
     init = (x, jnp.zeros((), jnp.float32))
-    unroll = True if get_scan_unroll() else 1
     if caches is not None:
         read = {b: {n: c for n, c in cache.items() if n not in STATES}
                 for b, cache in caches.items()}
@@ -187,8 +184,7 @@ def _scan_stack(cfg, stack_params, x, kinds: Tuple[str, ...], *,
 
         count = jax.tree.leaves(stack_params)[0].shape[0]
         (x, aux, states), rows = jax.lax.scan(
-            step, init + (carried,), (stack_params, read, jnp.arange(count)),
-            unroll=unroll)
+            step, init + (carried,), (stack_params, read, jnp.arange(count)))
         return x, {b: {**caches[b], **states[b],
                        **update_cache(cfg, caches[b], rows[b], cache_pos)}
                    for b in caches}, aux
@@ -201,8 +197,7 @@ def _scan_stack(cfg, stack_params, x, kinds: Tuple[str, ...], *,
                                                enc_out=enc_out)
             aux = aux + a
         return (h, aux), (ncs if collect_cache else None)
-    (x, aux), ys = jax.lax.scan(jax.checkpoint(body), init, stack_params,
-                                unroll=unroll)
+    (x, aux), ys = jax.lax.scan(jax.checkpoint(body), init, stack_params)
     return x, ys, aux
 
 

@@ -1,12 +1,11 @@
 """Mixture-of-Experts FFN with expert parallelism (EP) over the ``model``
 mesh axis.
 
-Dispatch is capacity-based (GShard-style, drop-on-overflow) but built for
-pod scale: tokens stay sharded over (pod, data); experts are sharded over
-``model``; the dispatch/return traffic is two explicit `all_to_all`s inside
-a `shard_map` — exactly the "critical edge" traffic pattern the paper's
-heterogeneous EdgeMatch penalizes for (§4.3), now as a first-class JAX
-collective the roofline can see.
+Dispatch is capacity-based (GShard-style, drop-on-overflow): tokens stay
+sharded over ``data``; experts are sharded over ``model``; the
+dispatch/return traffic is two explicit `all_to_all`s inside a `shard_map`
+— exactly the "critical edge" traffic pattern the paper's heterogeneous
+EdgeMatch penalizes for (§4.3), as a first-class JAX collective.
 
 Covers both assigned MoE archs:
   * deepseek-moe-16b — 2 shared + 64 routed, top-6, fine-grained (d_ff 1408)

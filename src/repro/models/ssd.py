@@ -14,15 +14,13 @@ blocking a TPU kernel wants (see kernels/ssd_scan.py).
 """
 from __future__ import annotations
 
-import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..kernels.ops import ssd_state_update
-from .common import (Params, dense_init, get_mesh_context, get_scan_unroll,
-                     rmsnorm)
+from .common import Params, dense_init, get_mesh_context, rmsnorm
 
 
 def ssd_init(cfg, key, dtype) -> Tuple[Params, Dict]:
@@ -141,8 +139,7 @@ def ssd_scan_ref(x, dt, A, B, C, chunk: int,
 
     inputs = (jnp.swapaxes(xc, 0, 1), jnp.swapaxes(dtc, 0, 1),
               jnp.swapaxes(Bc, 0, 1), jnp.swapaxes(Cc, 0, 1))
-    state, ys = jax.lax.scan(jax.checkpoint(step), state0, inputs,
-                             unroll=True if get_scan_unroll() else 1)
+    state, ys = jax.lax.scan(jax.checkpoint(step), state0, inputs)
     y = jnp.swapaxes(ys, 0, 1).reshape(Bsz, S, H, P)
     if return_state:
         return y.astype(x.dtype), state

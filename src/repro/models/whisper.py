@@ -1,7 +1,7 @@
 """Whisper-large-v3 backbone: encoder-decoder on the shared blocks.
 
-The conv/mel frontend is a STUB per the assignment: ``input_specs()``
-delivers precomputed frame embeddings (B, enc_seq, d_model).  Learned
+The conv/mel frontend is a STUB per the assignment: the batch's ``frames``
+are precomputed frame embeddings (B, enc_seq, d_model).  Learned
 positional embeddings (sized to the assigned shapes — the real model stops
 at 448 decoder positions; deviation noted in DESIGN.md), LayerNorm, GELU.
 """

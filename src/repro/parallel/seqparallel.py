@@ -1,6 +1,5 @@
-"""Sequence-parallel SSD scan: shard the 524k-token sequence across mesh
-devices and chain SSM states through `collective_permute` (SP for the
-long_500k shape).
+"""Sequence-parallel SSD scan: shard a long sequence across mesh devices
+and chain SSM states through `collective_permute`.
 
 Two-pass formulation (linear-recurrence prefix over devices):
 

@@ -7,24 +7,20 @@ mesh and parallelism recipe.
 Baseline recipe (paper-faithful tenant layout; §Perf iterates on it):
   * vocab / fused-head / ff / expert dims  -> "model"   (TP / EP)
   * d_model (param) dim                    -> "data"    (FSDP / ZeRO-3)
-  * batch                                  -> ("pod", "data") when multi-pod
+  * batch                                  -> "data"
   * attention q-sequence + split-KV cache  -> "model"   (inside shard_map /
                                                decode constraints)
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def is_multi_pod(mesh: Mesh) -> bool:
-    return "pod" in mesh.axis_names
-
-
 def batch_axes(mesh: Mesh) -> Tuple[str, ...]:
-    return ("pod", "data") if is_multi_pod(mesh) else ("data",)
+    return ("data",)
 
 
 def param_rules(mesh: Mesh, *, fsdp: bool = True) -> Dict[str, Any]:
@@ -86,7 +82,7 @@ def _axes_size(mesh: Mesh, axes) -> int:
 
 def _fit(mesh: Mesh, axes, dim_size: int):
     """Use ``axes`` for a dim only if the dim is divisible by their size
-    (long_500k has global_batch=1 — unshardable over 16-way data)."""
+    (a batch of 1 stays unsharded)."""
     return axes if dim_size % _axes_size(mesh, axes) == 0 else None
 
 
@@ -120,7 +116,7 @@ def cache_specs(cache_shapes, mesh: Mesh):
 
 
 def batch_specs(batch_shapes, mesh: Mesh):
-    """Input batches: shard the leading (batch) dim over (pod, data)."""
+    """Input batches: shard the leading (batch) dim over ``data``."""
     ba = batch_axes(mesh)
 
     def one(leaf):

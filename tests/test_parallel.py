@@ -1,6 +1,6 @@
 """Meshed tests (8 host devices, 2x4): sharded train/forward equivalence,
-sequence-parallel SSD exactness, vNPU->Mesh integration, elastic remap,
-simulator sanity, roofline parsing."""
+the attention core's shape-derived sharding, sequence-parallel SSD
+exactness, vNPU->Mesh integration, elastic remap, simulator sanity."""
 import dataclasses
 
 import jax
@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs import get_config, SHAPES
+from repro.configs import get_config
 from repro.configs.base import reduce_for_smoke
 from repro.launch.mesh import make_test_mesh
 from repro.models import build
@@ -25,32 +25,56 @@ def _mesh():
     return make_test_mesh((2, 4), ("data", "model"))
 
 
+def _meshed_forward(cfg, seq_len):
+    """(unmeshed logits, meshed logits, the meshed forward's lowered text)
+    of ``cfg`` on a batch of 4 x ``seq_len`` tokens, over the 2x4 mesh."""
+    mesh = _mesh()
+    bundle = build(cfg)
+    key = jax.random.PRNGKey(0)
+    clear_mesh_context()
+    params = bundle.init(key)
+    batch = {"tokens": jax.random.randint(key, (4, seq_len), 0, 255)}
+    ref = np.asarray(bundle.forward(params, batch), np.float32)
+
+    set_mesh_context(mesh, shd.batch_axes(mesh))
+    set_activation_rules(shd.activation_rules(mesh))
+    pshard = shd.named_shardings(
+        mesh, shd.param_specs(bundle.param_logical_axes(),
+                              shd.param_rules(mesh)))
+    bshard = shd.named_shardings(mesh, shd.batch_specs(batch, mesh))
+    with mesh:
+        fwd = jax.jit(bundle.forward, in_shardings=(pshard, bshard))
+        lowered = fwd.lower(params, batch).as_text()
+        out = fwd(jax.device_put(params, pshard),
+                  jax.device_put(batch, bshard))
+    return ref, np.asarray(out, np.float32), lowered
+
+
 class TestShardedEquivalence:
     @pytest.mark.parametrize("arch", ["llama3_2_1b", "hymba_1_5b"])
     def test_meshed_forward_matches_local(self, arch):
-        mesh = _mesh()
         cfg = dataclasses.replace(reduce_for_smoke(get_config(arch)),
                                   d_model=64, vocab_size=256,
                                   param_dtype="float32")
-        bundle = build(cfg)
-        key = jax.random.PRNGKey(0)
-        clear_mesh_context()
-        params = bundle.init(key)
-        batch = {"tokens": jax.random.randint(key, (4, 64), 0, 255)}
-        ref = np.asarray(bundle.forward(params, batch), np.float32)
+        ref, out, _ = _meshed_forward(cfg, 64)
+        np.testing.assert_allclose(ref, out, rtol=2e-3, atol=2e-3)
 
-        set_mesh_context(mesh, shd.batch_axes(mesh))
-        set_activation_rules(shd.activation_rules(mesh))
-        pshard = shd.named_shardings(
-            mesh, shd.param_specs(bundle.param_logical_axes(),
-                                  shd.param_rules(mesh)))
-        bshard = shd.named_shardings(mesh, shd.batch_specs(batch, mesh))
-        with mesh:
-            out = jax.jit(bundle.forward, in_shardings=(pshard, bshard))(
-                jax.device_put(params, pshard),
-                jax.device_put(batch, bshard))
-        np.testing.assert_allclose(ref, np.asarray(out, np.float32),
-                                   rtol=2e-3, atol=2e-3)
+    # model axis 4, 4 query heads: S=64 divides, so sequence shards; S=62
+    # does not, so head shards where the 4 KV heads divide, else none
+    @pytest.mark.parametrize("branch,seq_len,kv_heads", [
+        ("seq", 64, 2), ("heads", 62, 4), ("replicated", 62, 2)])
+    def test_attention_core_sharding_follows_shapes(self, branch, seq_len,
+                                                    kv_heads):
+        cfg = dataclasses.replace(
+            reduce_for_smoke(get_config("llama3_2_1b")), d_model=64,
+            vocab_size=256, n_kv_heads=kv_heads, param_dtype="float32")
+        ref, out, lowered = _meshed_forward(cfg, seq_len)
+        np.testing.assert_allclose(ref, out, rtol=2e-3, atol=2e-3)
+        gathers = "all_gather" in lowered      # seq's K/V all-gather
+        shard_map = "manual_computation" in lowered
+        assert (gathers, shard_map) == {"seq": (True, True),
+                                        "heads": (False, True),
+                                        "replicated": (False, False)}[branch]
 
     def test_moe_ep_matches_local_when_no_drops(self):
         from repro.models.moe import moe_forward, moe_init
@@ -127,71 +151,6 @@ class TestVMesh:
         d1 = {d.id for d in t1.mesh.devices.flat}
         d2 = {d.id for d in t2.mesh.devices.flat}
         assert not (d1 & d2)
-
-
-class TestRooflineParsing:
-    def test_collective_regex(self):
-        from repro.roofline import collective_bytes
-        hlo = """
-  %ag = bf16[2,1024,128]{2,1,0} all-gather(%x), replica_groups={}
-  %ar = f32[512]{0} all-reduce(%y), to_apply=%add
-  %cp = bf16[4,4]{1,0} collective-permute(%z)
-"""
-        out = collective_bytes(hlo)
-        assert out["all-gather"] == 2 * 1024 * 128 * 2
-        assert out["all-reduce"] == 512 * 4
-        assert out["collective-permute"] == 32
-
-    def test_while_aware_multiplies_trip_count(self):
-        from repro.roofline import collective_bytes_while_aware
-        hlo = """
-%cond.1 (a: s32[]) -> pred[] {
-  %c = s32[] constant(24)
-  ROOT %cmp = pred[] compare(%a, %c), direction=LT
-}
-
-%body.1 (a: s32[]) -> s32[] {
-  %ar = f32[128]{0} all-reduce(%p), to_apply=%add
-  ROOT %n = s32[] add(%a, %one)
-}
-
-ENTRY %main (p: f32[128]) -> f32[128] {
-  %w = s32[] while(s32[] %i), condition=%cond.1, body=%body.1
-  %ag = f32[64]{0} all-gather(%p)
-  ROOT %r = f32[128] %p
-}
-"""
-        out = collective_bytes_while_aware(hlo)
-        assert out["all-reduce"] == 24 * 128 * 4
-        assert out["all-gather"] == 64 * 4
-
-    def test_analytic_flops_match_xla_on_dense(self):
-        """Analytic model vs unrolled XLA cost analysis (small dense cell)."""
-        from repro.roofline.analytic import step_flops
-        from repro.models.common import set_scan_unroll
-        cfg = dataclasses.replace(
-            reduce_for_smoke(get_config("llama3_2_1b")),
-            d_model=64, vocab_size=256, n_layers=2)
-        shape = dataclasses.replace(SHAPES["train_4k"], seq_len=128,
-                                    global_batch=4)
-        analytic = step_flops(cfg, shape)
-        bundle = build(cfg)
-        from repro.train import AdamWConfig, TrainConfig, init_state, \
-            make_train_step
-        tcfg = TrainConfig(opt=AdamWConfig())
-        step = make_train_step(bundle.loss, tcfg)
-        state = jax.eval_shape(lambda: init_state(
-            bundle.init(jax.random.PRNGKey(0)), tcfg.opt))
-        batch = {"tokens": jax.ShapeDtypeStruct((4, 128), jnp.int32)}
-        set_scan_unroll(True)
-        try:
-            c = jax.jit(step).lower(state, batch).compile()
-        finally:
-            set_scan_unroll(False)
-        from repro.roofline import cost_analysis_dict
-        xla = float(cost_analysis_dict(c).get("flops", 0))
-        assert xla > 0
-        assert 0.5 < analytic / xla < 2.0
 
 
 class TestSimulatorSanity:
